@@ -87,6 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CameraIntrinsics, ORBConfig, PipelineConfig,
                         RigConfig, VisualSystem, backend,
                         extract_features, pipeline_schedule)
@@ -304,11 +305,10 @@ def table3_accuracy(quick=False):
 
 
 def table4_throughput(quick=False):
-    """Tab. IV: frontend fps at the paper's two resolutions, on this
-    CPU (measured) and on TPU v5e (roofline model from kernel
-    flops/bytes).  Paper: 69 fps @640x480, 50.7 fps @1280x720 (FPGA);
-    9 fps (TX1), 15 fps (i7) @720p."""
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+    """Tab. IV: frontend fps at the paper's two resolutions on this
+    host's CPU.  Paper: 69 fps @640x480, 50.7 fps @1280x720 (FPGA);
+    9 fps (TX1), 15 fps (i7) @720p.  No device number is modelled
+    here: a chip rate comes only from a run on the chip."""
     resolutions = [(480, 640)] + ([] if quick else [(720, 1280)])
     for h, w in resolutions:
         frames, poses, intr, _ = _scene(h, w, n=400)
@@ -319,18 +319,6 @@ def table4_throughput(quick=False):
         t, _ = _bench(step, frames[0, 0], frames[0, 1], iters=3)
         emit("table4", f"cpu_fps_{w}x{h}", round(1.0 / t, 1), "fps",
              "this host, one stereo pair")
-        # v5e roofline model: frontend is stencil/popcount bound ->
-        # bytes-dominated; count pyramid+blur+fast traffic + matcher
-        px = h * w * (1 + 1 / (ocfg.scale_factor ** 2))
-        bytes_img = px * 4 * 6          # score map, blur, pyramid r/w
-        flops_img = px * (16 * 3 * 9 + 49 * 2)  # fast arcs + blur taps
-        k = ocfg.max_features
-        bytes_match = k * k * 4 + k * 32 * 2
-        t_mem = (2 * bytes_img + bytes_match) / HBM_BW
-        t_cmp = (2 * flops_img + k * k * 256 * 2) / PEAK_FLOPS_BF16
-        fps = 1.0 / max(t_mem, t_cmp)
-        emit("table4", f"v5e_model_fps_{w}x{h}", round(fps, 0), "fps",
-             "roofline bound, one chip")
     emit("table4", "paper_fpga_fps", "69/50.7", "fps",
          "640x480 / 1280x720")
     emit("table4", "paper_baselines_720p", "TX1 9, i7 15", "fps",
@@ -864,12 +852,6 @@ def table_localization(quick=False):
     ocfg = ORBConfig(height=h, width=w, max_features=kmax,
                      fast_threshold=15)
     res = f"{w}x{h}"
-    # Pinned at ~2x the worst measured baseline across quick/full AND
-    # f32/u8 (measured 2026-08: ATE 0.19-0.29 m, RPE-t 0.10-0.10 m,
-    # RPE-r 0.10-0.14 deg) — tight enough to catch a solver or matcher
-    # regression, loose enough to absorb accelerator reduction-order
-    # jitter.
-    limits = {"ate": 0.60, "rpe_trans": 0.25, "rpe_rot": 0.30}
 
     def gate(tag, vs, fr):
         t_wall, out = _bench(vs.run, fr, iters=3, warmup=1)
@@ -884,13 +866,11 @@ def table_localization(quick=False):
              "per-transition robust-solve support")
         emit("localization", f"travel_{tag}", round(m["travel_m"], 3),
              "m", "ground-truth path length")
-        for key, metric, unit in (("ate", "ate_rmse_m", "m"),
-                                  ("rpe_trans", "rpe_trans_rmse_m", "m"),
-                                  ("rpe_rot", "rpe_rot_mean_deg", "deg")):
+        for key, (metric, limit, unit) in loc.ACCURACY_LIMITS.items():
             emit("accuracy_gate", f"{key}_{tag}", round(m[metric], 4),
                  unit, f"{t_total}-frame constant-twist scene {res} "
                  "vs ground truth")
-            emit("accuracy_gate", f"{key}_{tag}_limit", limits[key],
+            emit("accuracy_gate", f"{key}_{tag}_limit", limit,
                  unit, "pinned ~2x the measured baseline")
         return out
 
@@ -1027,6 +1007,7 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_frontend.json",
                     help="JSON artifact path ('' to disable)")
     args = ap.parse_args()
+    enable_compile_cache()
     print("table,name,value,unit,note")
     t0 = time.time()
     table1_latency_split(args.quick)

@@ -10,7 +10,7 @@ data, no kernel execution, no TPU) and the traced program is audited:
                    multiplier (scan × length, cond worst-case branch,
                    while = unbounded) — the launch-budget proof
   ``vmem``         per-launch resident bytes from the BlockSpecs/grid
-                   (Unblocked halos included) vs a per-core budget
+                   (Element halo windows included) vs a per-core budget
   ``dtype_flow``   silent-widening lint over kernel-body jaxprs
                    (float in an all-integer kernel, float64 anywhere,
                    weak-type promotions)

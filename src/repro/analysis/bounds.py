@@ -7,14 +7,15 @@ a slab index that ignores the shape table) reads garbage — silently on
 interpret-mode CPU.  This checker closes that gap abstractly: it
 evaluates every ``index_map_jaxpr`` over its ENTIRE grid with
 ``jax.core.eval_jaxpr`` (pure python, no compilation — grids here are a
-few hundred points) and proves, per dimension:
+few hundred points; scalar-prefetch operands, which these index maps do
+not read, are fed zeros) and proves, per dimension:
 
-  * ``Blocked`` mode — the returned BLOCK index ``b`` satisfies
+  * a blocked dim — the returned BLOCK index ``b`` satisfies
     ``0 <= b`` and ``b * block < dim`` (the block's first element is
     inside the array; pallas pads the tail block);
-  * ``Unblocked`` mode — the returned ELEMENT start ``s`` satisfies
+  * a ``pl.Element`` dim — the returned ELEMENT start ``s`` satisfies
     ``-lo <= s`` and ``s + block <= dim + hi`` where ``(lo, hi)`` is
-    the mode's declared padding (none by default) — halo windows must
+    the dim's declared padding (none by default) — halo windows must
     sit entirely inside the pre-padded slab.
 """
 
@@ -27,6 +28,7 @@ import jax.core as jcore
 import numpy as np
 
 from repro.analysis.jaxpr_walk import PallasSite
+from repro.analysis.vmem import block_dim
 
 __all__ = ["BoundsViolation", "check_bounds"]
 
@@ -44,47 +46,48 @@ class BoundsViolation:
     message: str
 
 
-def _pad(indexing_mode, rank: int) -> list[tuple[int, int]]:
-    pad = getattr(indexing_mode, "padding", None)
-    if pad is None:
-        return [(0, 0)] * rank
-    return [(int(lo), int(hi)) for lo, hi in pad]
+def _operand_zeros(avals) -> list:
+    """Zero stand-ins for the scalar-prefetch operands of an index map."""
+    out = []
+    for aval in avals:
+        inner = getattr(aval, "inner_aval", aval)
+        out.append(np.zeros(inner.shape, inner.dtype))
+    return out
 
 
 def _check_mapping(site: PallasSite, bm, grid) -> list[BoundsViolation]:
     closed = bm.index_map_jaxpr
-    block = tuple(bm.block_shape)
-    dims = tuple(int(d) for d in bm.array_shape_dtype.shape)
-    mode = type(bm.indexing_mode).__name__
+    dims = tuple(int(d) for d in bm.array_aval.shape)
     origin = str(getattr(bm, "origin", "?"))
-    if len(closed.jaxpr.invars) != len(grid):
+    invars = closed.jaxpr.invars
+    if len(invars) < len(grid):
         return [BoundsViolation(
             site.name, origin, (), -1,
-            f"index_map takes {len(closed.jaxpr.invars)} args but the "
+            f"index_map takes {len(invars)} args but the "
             f"grid has rank {len(grid)} — cannot evaluate")]
-    pad = _pad(bm.indexing_mode, len(block))
+    operands = _operand_zeros(v.aval for v in invars[len(grid):])
     out: list[BoundsViolation] = []
     for point in itertools.product(*(range(g) for g in grid)):
         idx = jcore.eval_jaxpr(closed.jaxpr, closed.consts,
-                               *(np.int32(p) for p in point))
+                               *(np.int32(p) for p in point), *operands)
         for d, raw in enumerate(idx):
             v = int(raw)
-            bs = block[d] if isinstance(block[d], int) else 1
+            bd = bm.block_shape[d]
+            bs = block_dim(bd)
             dim = dims[d] if d < len(dims) else 1
-            if mode == "Unblocked":
-                lo, hi = pad[d]
+            if type(bd).__name__ == "Element":
+                lo, hi = (int(p) for p in bd.padding)
                 if v < -lo or v + bs > dim + hi:
                     out.append(BoundsViolation(
                         site.name, origin, point, d,
                         f"element window [{v}, {v + bs}) escapes "
                         f"dim {d} of extent {dim} "
                         f"(padding ({lo}, {hi}))"))
-            else:
-                if v < 0 or v * bs >= dim:
-                    out.append(BoundsViolation(
-                        site.name, origin, point, d,
-                        f"block index {v} (block {bs}) escapes dim "
-                        f"{d} of extent {dim}"))
+            elif v < 0 or v * bs >= dim:
+                out.append(BoundsViolation(
+                    site.name, origin, point, d,
+                    f"block index {v} (block {bs}) escapes dim "
+                    f"{d} of extent {dim}"))
             if len(out) >= _MAX_VIOLATIONS:
                 return out
     return out

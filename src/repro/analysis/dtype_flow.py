@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.core as jcore
+import jax.extend.core as jcore
 import jax.numpy as jnp
 
 from repro.analysis.jaxpr_walk import PallasSite
@@ -79,7 +79,7 @@ def _integer_contract(site: PallasSite) -> bool:
     """True when EVERY traced operand block (inputs and outputs) of the
     launch is integer/bool — the fixed-point contract then holds for
     the whole kernel body."""
-    dtypes = [bm.array_shape_dtype.dtype
+    dtypes = [bm.array_aval.dtype
               for bm in site.grid_mapping.block_mappings]
     return bool(dtypes) and not any(
         jnp.issubdtype(d, jnp.floating) for d in dtypes)

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.core as jcore
+import jax.extend.core as jcore
 
 __all__ = ["PallasSite", "LaunchCount", "pallas_sites", "count_launches"]
 

@@ -104,7 +104,7 @@ class TracedEntry:
     agree before either is compared to the benchmark artifact)."""
 
     spec: EntrySpec
-    closed: jax.core.ClosedJaxpr
+    closed: jax.extend.core.ClosedJaxpr
     sites: list[jaxpr_walk.PallasSite]
     count: jaxpr_walk.LaunchCount
     audit_count: int

@@ -2,9 +2,10 @@
 
 The paper's FPGA flow proves BRAM fit at synthesis; the TPU analog is
 the per-core VMEM a launch keeps resident: one block per operand per
-grid step (input AND output BlockSpecs), with ``pl.Unblocked`` windows
-counted at their full block shape — halos included, exactly the bytes
-the kernel touches.  ``launch_vmem`` reads the traced
+grid step (input AND output BlockSpecs), with ``pl.Element`` windows
+(the halo'd row bands of the dense stencils) counted at their full
+block shape — halos included, exactly the bytes the kernel touches.
+Scalar-prefetch operands live in SMEM and are not counted.  ``launch_vmem`` reads the traced
 ``grid_mapping.block_mappings`` of a :class:`~.jaxpr_walk.PallasSite`
 and reports:
 
@@ -12,10 +13,10 @@ and reports:
     operand: the floor any schedule must hold resident (this is the
     accounting behind the repro's 7.91 MiB/pair @720p f32 / 1.98 MiB
     uint8 numbers), and the number the budget gates;
-  * ``pipelined_bytes`` — the same with double buffering (×2), the
-    steady-state working set of the default pipelined schedule,
-    reported for context but NOT gated (the compiler may or may not
-    double-buffer each operand).
+  * ``pipelined_bytes`` — the same with each operand's pipeline
+    buffer count (2 by default, 1 for ``pl.Buffered(1)`` resident
+    slabs), the steady-state working set of the pipelined schedule,
+    reported for context but NOT gated.
 
 The default budget is 16 MiB — one TPU core's VMEM.  A 1080p float32
 FM slab pair (≈17.1 MiB) correctly fails it; the 720p matrix passes.
@@ -43,8 +44,9 @@ class BlockUsage:
     origin: str               # 'args[i]' / 'outputs[i]' per the trace
     block_shape: tuple        # as written in the BlockSpec (halos incl.)
     dtype: str
-    mode: str                 # 'Blocked' | 'Unblocked'
+    mode: str                 # 'Blocked' | 'Element'
     nbytes: int
+    buffers: int = 2          # pipeline buffers the schedule allocates
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +57,7 @@ class LaunchVmem:
     grid: tuple
     blocks: tuple[BlockUsage, ...]
     resident_bytes: int       # 1 buffer per operand (gated)
-    pipelined_bytes: int      # 2 buffers per operand (reported)
+    pipelined_bytes: int      # pipeline buffers per operand (reported)
     budget: int
 
     @property
@@ -63,23 +65,32 @@ class LaunchVmem:
         return self.resident_bytes <= self.budget
 
 
-def _block_elems(block_shape) -> int:
-    # Squeezed dims show up as pallas' `mapped` sentinel / None — they
-    # contribute one element row, not zero.
-    return math.prod(
-        int(d) if isinstance(d, int) else 1 for d in block_shape)
+def block_dim(d) -> int:
+    """Extent of one block dim: an int, a ``pl.Blocked`` /
+    ``pl.Element`` (its ``block_size``), or squeezed (one row)."""
+    if isinstance(d, int):
+        return d
+    return int(getattr(d, "block_size", 1) or 1)
+
+
+def block_mode(bm) -> str:
+    """'Element' when the block is indexed by element offsets (Pallas
+    requires all dims or none to be), else 'Blocked'."""
+    return ("Element" if any(type(d).__name__ == "Element"
+                             for d in bm.block_shape) else "Blocked")
 
 
 def _usage(bm) -> BlockUsage:
-    dtype = bm.array_shape_dtype.dtype
-    mode = type(bm.indexing_mode).__name__
-    shape = tuple(bm.block_shape)
+    dtype = bm.array_aval.dtype
+    shape = tuple(block_dim(d) for d in bm.block_shape)
+    buffers = getattr(bm.pipeline_mode, "buffer_count", None) or 2
     return BlockUsage(
         origin=str(getattr(bm, "origin", "?")),
         block_shape=shape,
         dtype=str(dtype),
-        mode=mode,
-        nbytes=_block_elems(shape) * dtype.itemsize)
+        mode=block_mode(bm),
+        nbytes=math.prod(shape) * dtype.itemsize,
+        buffers=int(buffers))
 
 
 def launch_vmem(site: PallasSite,
@@ -94,5 +105,5 @@ def launch_vmem(site: PallasSite,
         grid=tuple(int(g) for g in gm.grid),
         blocks=blocks,
         resident_bytes=resident,
-        pipelined_bytes=2 * resident,
+        pipelined_bytes=sum(b.nbytes * b.buffers for b in blocks),
         budget=int(budget))

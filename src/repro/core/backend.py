@@ -12,6 +12,7 @@ compact stereo visual-odometry backend in JAX:
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -38,12 +39,15 @@ def kabsch(pts_a: jnp.ndarray, pts_b: jnp.ndarray,
     cb = jnp.sum(w[:, None] * pts_b, axis=0)
     a0 = pts_a - ca
     b0 = pts_b - cb
-    h = (w[:, None] * a0).T @ b0                      # (3, 3)
+    # f32 products throughout: on the TPU a default-precision f32
+    # matmul multiplies in bf16 passes, which would round the pose.
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    h = mm((w[:, None] * a0).T, b0)                   # (3, 3)
     u, _, vt = jnp.linalg.svd(h)
-    d = jnp.sign(jnp.linalg.det(vt.T @ u.T))
+    d = jnp.sign(jnp.linalg.det(mm(vt.T, u.T)))
     s = jnp.diag(jnp.asarray([1.0, 1.0, 1.0])).at[2, 2].set(d)
-    r = vt.T @ s @ u.T
-    t = cb - r @ ca
+    r = mm(mm(vt.T, s), u.T)
+    t = cb - mm(r, ca)
     return r, t
 
 

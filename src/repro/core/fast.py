@@ -28,16 +28,36 @@ from repro.kernels.ref import PATCH, RADIUS  # noqa: F401
 
 def select_topk(score: jnp.ndarray, k: int, border: int):
     """Top-K corners of a score map. Returns (xy (K,2) int32, score (K,),
-    valid (K,) bool)."""
+    valid (K,) bool), ordered by score descending, ties by the lower
+    flat index first.
+
+    ``lax.top_k`` promises that tie order, but the TPU lowers it to
+    chunked sorts whose comparator sees values only, so equal scores
+    (FAST scores are small integers: ties are common) came back in an
+    order that changed with the batch size.  Here top-K only supplies
+    the K-th value; which of the tied corners at that value are kept
+    (the lowest indices), and the order of the K, are decided
+    explicitly."""
     h, w = score.shape
     row = jnp.arange(h)[:, None]
     col = jnp.arange(w)[None, :]
     inside = ((row >= border) & (row < h - border)
               & (col >= border) & (col < w - border))
-    masked = jnp.where(inside, score, jnp.zeros_like(score))
-    vals, idx = jax.lax.top_k(masked.reshape(-1), k)
-    ys = (idx // w).astype(jnp.int32)
-    xs = (idx % w).astype(jnp.int32)
+    flat = jnp.where(inside, score, jnp.zeros_like(score)).reshape(-1)
+    kth = jax.lax.top_k(flat, k)[0][k - 1]
+    above = flat > kth
+    at = flat == kth
+    n_at = k - jnp.sum(above.astype(jnp.int32))
+    keep = above | (at & (jnp.cumsum(at.astype(jnp.int32)) <= n_at))
+    # Exactly k corners are kept; their negated flat indices are unique
+    # keys, so this top-K is tie-free and returns them by index.
+    pos = jnp.arange(flat.shape[0], dtype=jnp.int32)
+    idx = -jax.lax.top_k(jnp.where(keep, -pos, jnp.iinfo(jnp.int32).min),
+                         k)[0]
+    neg_vals, idx = jax.lax.sort((-flat[idx], idx), num_keys=2)
+    vals = -neg_vals
+    ys = idx // w
+    xs = idx % w
     valid = vals > 0
     return jnp.stack([xs, ys], axis=-1), vals, valid
 

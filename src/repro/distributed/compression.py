@@ -32,7 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as Ps
 
 from repro.core.types import DepthSet, FeatureSet, MatchSet, PoseSet
@@ -107,7 +107,7 @@ def compressed_psum(tree, mesh: Mesh, axis: str = "data"):
     spec = Ps(*(None,) * cat.ndim)
 
     @functools.partial(shard_map, mesh=mesh, in_specs=spec,
-                       out_specs=spec, check_rep=False)
+                       out_specs=spec, check_vma=False)
     def run(v):
         return _ring_allreduce_int8(v, axis, n)
 

@@ -17,7 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as Ps
 
 
@@ -39,7 +39,7 @@ def pipeline_forward(stage_fn, mesh: Mesh, axis: str, stage_params,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(p_spec, Ps()), out_specs=Ps(),
-        check_rep=False)
+        check_vma=False)
     def run(params, xm):
         params = jax.tree.map(lambda a: a[0], params)   # local stage slice
         sid = jax.lax.axis_index(axis)

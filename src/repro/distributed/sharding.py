@@ -194,12 +194,11 @@ def shard_over(fn, mesh: Mesh, axis: str, arg_axis: int = 0):
     ``axis`` and every output leaf keeps that axis as its leading
     dimension.  Used by ``core.pipeline.VisualSystem`` to shard the
     fleet rig axis; the per-device program is the unmodified fused
-    3-launch datapath."""
-    from jax.experimental.shard_map import shard_map
-
+    3-launch datapath.  ``check_vma`` is off because the Pallas calls
+    inside carry no varying-manual-axes annotations."""
     in_spec = PartitionSpec(*([None] * arg_axis + [axis]))
-    return shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=in_spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=in_spec, check_vma=False)
 
 
 def spec_for(axes: Sequence[str | None], shape: Sequence[int],

@@ -2,12 +2,12 @@
 
 TPU adaptation of the paper's FAST Detection module (Sec. III-C).  The
 FPGA streams the image through line buffers and register banks; here the
-image is tiled into halo'd VMEM blocks (``pl.Unblocked`` indexing gives
-the overlapping 3-pixel halo the Bresenham-16 circle needs) and the 16
-taps become static VREG shifts of the tile — the register-bank analog.
-
-Block shape: (TILE_H + 6, TILE_W + 6) float32 in VMEM; default 128x128
-output tiles (~70 KB in + 64 KB out), MXU-free, pure VPU stencil.
+image is cut into full-width row bands with a 3-pixel halo
+(``frontend_fused.row_band_spec``); each grid step slices its
+(TILE_H + 6, TILE_W + 6) window, which gives the overlapping halo the
+Bresenham-16 circle needs, and the 16 taps become static VREG shifts of
+the tile — the register-bank analog.  Default 128x128 output tiles,
+MXU-free, pure VPU stencil.
 """
 
 from __future__ import annotations
@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.frontend_fused import halo_window, row_band_spec
+
 from repro.kernels.ref import ARC_LEN, CIRCLE16
 
 TILE_H = 128
@@ -26,7 +28,7 @@ HALO = 3
 
 
 def _kernel(x_ref, o_ref, *, threshold: float, tile_h: int, tile_w: int):
-    x = x_ref[...]                                   # (tile_h+6, tile_w+6)
+    x = halo_window(x_ref, HALO, tile_w)           # (tile_h+6, tile_w+6)
     center = x[HALO:HALO + tile_h, HALO:HALO + tile_w]
     # 16 circle taps as static shifted views of the halo'd tile.
     taps = [
@@ -66,10 +68,8 @@ def fast_score_map_pallas(padded: jnp.ndarray, *, threshold: float,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec(
-            (TILE_H + 2 * HALO, TILE_W + 2 * HALO),
-            lambda i, j: (i * TILE_H, j * TILE_W),
-            indexing_mode=pl.Unblocked())],
+        in_specs=[row_band_spec(TILE_H + 2 * HALO, w + 2 * HALO, False,
+                                TILE_H)],
         out_specs=pl.BlockSpec((TILE_H, TILE_W), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         interpret=interpret,

@@ -25,8 +25,13 @@ once instead of once per op.  Two entry points share one tile body:
 Halo arithmetic: blur and FAST both need a 3-pixel stencil halo; fusing
 the 3x3 NMS needs the *raw score* one pixel beyond the tile, and that
 score row/column needs its own 3-pixel image halo — hence FUSED_HALO=4
-(vs. HALO=3 for the unfused kernels).  Block = (1, TILE+8, TILE+8) f32
-in VMEM via ``pl.Unblocked`` overlapping indexing; two (1, TILE, TILE)
+(vs. HALO=3 for the unfused kernels).  Mosaic only takes blocks whose
+last two dims are (8, 128)-aligned or span the array, so a (TILE+8)^2
+halo window cannot be a block: the input block is instead the full-width
+row band (1, TILE+8, W+8) at element row offset i*TILE (``pl.Element``),
+fetched once per (slab, tile row) because its index is constant along
+the tile-column grid axis, and each step slices its (TILE+8, TILE+8)
+window at the 128-aligned lane offset j*TILE.  Two (1, TILE, TILE)
 outputs.  MXU-free, pure VPU stencil.
 
 Boundary semantics match the ``ref.py`` oracle chain exactly:
@@ -45,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import (ARC_LEN, CIRCLE16, GAUSS7_NORM,
                                GAUSS7_WEIGHTS_INT, int_threshold)
@@ -207,22 +213,49 @@ def _slab_dtypes(padded, quantized: bool):
     return padded.astype(jnp.float32), (jnp.float32, jnp.float32)
 
 
+def halo_window(x_ref, halo: int, tile_w: int):
+    """This grid step's (tile_h + 2*halo, tile_w + 2*halo) window of a
+    full-width row-band block: the lane offset j * tile_w is a multiple
+    of 128, the alignment Mosaic needs for a dynamic lane slice."""
+    j = pl.program_id(x_ref.ndim - 1)
+    col = pl.multiple_of(j * tile_w, tile_w)
+    if x_ref.ndim == 3:
+        return x_ref[0, :, pl.ds(col, tile_w + 2 * halo)]
+    return x_ref[:, pl.ds(col, tile_w + 2 * halo)]
+
+
+def row_band_spec(rows: int, width: int, batched: bool, tile_h: int):
+    """BlockSpec of a full-width row band starting at element row
+    i * tile_h (halo rows included); index constant along the
+    tile-column axis, so the band is fetched once per tile row.  The
+    index map takes any trailing scalar-prefetch refs."""
+    if batched:
+        return pl.BlockSpec(
+            (pl.Element(1), pl.Element(rows), pl.Element(width)),
+            lambda bb, i, j, *_: (bb, i * tile_h, 0))
+    return pl.BlockSpec((pl.Element(rows), pl.Element(width)),
+                        lambda i, j, *_: (i * tile_h, 0))
+
+
 def _kernel(x_ref, blur_ref, score_ref, *, threshold: float, nms: bool,
             quantized: bool, true_h: int, true_w: int,
             tile_h: int, tile_w: int):
-    blur, out = _tile_outputs(x_ref[0], true_h, true_w, threshold=threshold,
+    blur, out = _tile_outputs(halo_window(x_ref, FUSED_HALO, tile_w),
+                              true_h, true_w, threshold=threshold,
                               nms=nms, quantized=quantized,
                               tile_h=tile_h, tile_w=tile_w)
     blur_ref[...] = blur[None]
     score_ref[...] = out[None]
 
 
-def _kernel_pyramid(x_ref, hw_ref, blur_ref, score_ref, *, threshold: float,
+def _kernel_pyramid(hw_ref, x_ref, blur_ref, score_ref, *, threshold: float,
                     nms: bool, quantized: bool, tile_h: int, tile_w: int):
     """Whole-pyramid variant: the slab's true (h, w) comes from the
-    per-slab shape table instead of static kwargs — every other
-    instruction is shared with the per-level kernel."""
-    blur, out = _tile_outputs(x_ref[0], hw_ref[0, 0], hw_ref[0, 1],
+    scalar-prefetched (SMEM) shape table instead of static kwargs —
+    every other instruction is shared with the per-level kernel."""
+    bb = pl.program_id(0)
+    blur, out = _tile_outputs(halo_window(x_ref, FUSED_HALO, tile_w),
+                              hw_ref[2 * bb], hw_ref[2 * bb + 1],
                               threshold=threshold, nms=nms,
                               quantized=quantized,
                               tile_h=tile_h, tile_w=tile_w)
@@ -255,10 +288,8 @@ def frontend_fused_pallas(padded: jnp.ndarray, *, threshold: float,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec(
-            (1, TILE_H + 2 * FUSED_HALO, TILE_W + 2 * FUSED_HALO),
-            lambda bb, i, j: (bb, i * TILE_H, j * TILE_W),
-            indexing_mode=pl.Unblocked())],
+        in_specs=[row_band_spec(TILE_H + 2 * FUSED_HALO,
+                                w + 2 * FUSED_HALO, True, TILE_H)],
         out_specs=[
             pl.BlockSpec((1, TILE_H, TILE_W), lambda bb, i, j: (bb, i, j)),
             pl.BlockSpec((1, TILE_H, TILE_W), lambda bb, i, j: (bb, i, j)),
@@ -288,11 +319,8 @@ def frontend_fused_pyramid_pallas(padded: jnp.ndarray, hw: jnp.ndarray, *,
     padding region emit only the -1/0 sentinels and never win NMS.
     Returns (blur, score), each (N, Hc, Wc): float32 pair for float
     input, (uint8, int16) for uint8 slabs (integer datapath); callers
-    slice each slab back to its true shape.
-
-    TPU-validation note: the (1, 2) int32 shape-table block rides in the
-    default memory space; on a real Mosaic build it belongs in SMEM
-    (scalar prefetch), like the keypoint blocks of ``describe_fused``.
+    slice each slab back to its true shape.  The shape table is
+    flattened to (2N,) and scalar-prefetched into SMEM.
     """
     padded, out_dtypes = _slab_dtypes(padded, quantized)
     n = padded.shape[0]
@@ -302,23 +330,20 @@ def frontend_fused_pyramid_pallas(padded: jnp.ndarray, hw: jnp.ndarray, *,
     kern = functools.partial(
         _kernel_pyramid, threshold=float(threshold), nms=bool(nms),
         quantized=bool(quantized), tile_h=TILE_H, tile_w=TILE_W)
+    out_block = pl.BlockSpec((1, TILE_H, TILE_W),
+                             lambda bb, i, j, hw_ref: (bb, i, j))
     return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, TILE_H + 2 * FUSED_HALO, TILE_W + 2 * FUSED_HALO),
-                lambda bb, i, j: (bb, i * TILE_H, j * TILE_W),
-                indexing_mode=pl.Unblocked()),
-            pl.BlockSpec((1, 2), lambda bb, i, j: (bb, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE_H, TILE_W), lambda bb, i, j: (bb, i, j)),
-            pl.BlockSpec((1, TILE_H, TILE_W), lambda bb, i, j: (bb, i, j)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[row_band_spec(TILE_H + 2 * FUSED_HALO,
+                                    w + 2 * FUSED_HALO, True, TILE_H)],
+            out_specs=[out_block, out_block],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((n, h, w), out_dtypes[0]),
             jax.ShapeDtypeStruct((n, h, w), out_dtypes[1]),
         ],
         interpret=interpret,
-    )(padded, hw.astype(jnp.int32))
+    )(hw.astype(jnp.int32).reshape(-1), padded)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.frontend_fused import halo_window, row_band_spec
+
 from repro.kernels.ref import GAUSS7_NORM, GAUSS7_WEIGHTS_INT
 
 TILE_H = 128
@@ -27,7 +29,7 @@ HALO = 3
 
 
 def _kernel(x_ref, o_ref, *, quantized: bool, tile_h: int, tile_w: int):
-    x = x_ref[...]                                # (tile_h+6, tile_w+6) f32
+    x = halo_window(x_ref, HALO, tile_w)        # (tile_h+6, tile_w+6) f32
     w = [float(v) for v in GAUSS7_WEIGHTS_INT]
     # Horizontal pass on the full halo'd tile (keeps vertical halo rows).
     horiz = None
@@ -59,10 +61,8 @@ def gaussian_blur7_pallas(padded: jnp.ndarray, *, quantized: bool = True,
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[pl.BlockSpec(
-            (TILE_H + 2 * HALO, TILE_W + 2 * HALO),
-            lambda i, j: (i * TILE_H, j * TILE_W),
-            indexing_mode=pl.Unblocked())],
+        in_specs=[row_band_spec(TILE_H + 2 * HALO, w + 2 * HALO, False,
+                                TILE_H)],
         out_specs=pl.BlockSpec((TILE_H, TILE_W), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         interpret=interpret,

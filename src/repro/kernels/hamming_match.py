@@ -35,26 +35,36 @@ def _popcount32(x: jnp.ndarray) -> jnp.ndarray:
     return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
 
 
-def masked_hamming(dl, ml, dr, mr, *, row_band: float,
+def masked_hamming(dl, ml, dr_t, mr_t, *, row_band: float,
                    max_disparity: float):
-    """(BK, 8) x (BM, 8) uint32 descriptors + (x, y, level, valid) meta
-    -> (BK, BM) int32 Hamming distances with the Search Region Decision
-    (paper Sec. III-D) fused as a BIG-sentinel mask.  The shared front
-    half of every matcher kernel body — this per-pair kernel and the
-    pair-folded grids of ``matcher_fused.py``."""
+    """(BK, 8) uint32 left descriptors + (BK, 4) (x, y, level, valid)
+    meta against the TRANSPOSED right side — (8, BM) descriptors and
+    (4, BM) meta, so every right-side row is lane-dense — -> (BK, BM)
+    int32 Hamming distances with the Search Region Decision (paper Sec.
+    III-D) fused as a BIG-sentinel mask.  The shared front half of every
+    matcher kernel body — this per-pair kernel and the pair-folded
+    grids of ``matcher_fused.py``."""
     # Hamming distance, accumulated word-by-word to keep VMEM small.
-    dist = jnp.zeros((dl.shape[0], dr.shape[0]), jnp.int32)
+    dist = jnp.zeros((dl.shape[0], dr_t.shape[1]), jnp.int32)
     for word in range(dl.shape[1]):
-        x = jnp.bitwise_xor(dl[:, word][:, None], dr[:, word][None, :])
+        x = jnp.bitwise_xor(dl[:, word:word + 1], dr_t[word:word + 1, :])
         dist = dist + _popcount32(x)
 
-    dx = ml[:, 0][:, None] - mr[:, 0][None, :]            # x_L - x_R
-    dy = jnp.abs(ml[:, 1][:, None] - mr[:, 1][None, :])
-    same_level = ml[:, 2][:, None] == mr[:, 2][None, :]
-    valid = (ml[:, 3][:, None] > 0.5) & (mr[:, 3][None, :] > 0.5)
+    dx = ml[:, 0:1] - mr_t[0:1, :]                         # x_L - x_R
+    dy = jnp.abs(ml[:, 1:2] - mr_t[1:2, :])
+    same_level = ml[:, 2:3] == mr_t[2:3, :]
+    valid = (ml[:, 3:4] > 0.5) & (mr_t[3:4, :] > 0.5)
     mask = (dy <= row_band) & (dx >= 0.0) & (dx <= max_disparity) \
         & same_level & valid
     return jnp.where(mask, dist, BIG)
+
+
+def first_argmin(dist, best):
+    """Lowest column index attaining the row minimum ``best`` ((R, 1))
+    of ``dist`` ((R, C) int32): first-occurrence argmin, as (R, 1)."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1)
+    return jnp.min(jnp.where(dist == best, cols, dist.shape[1]), axis=1,
+                   keepdims=True)
 
 
 def _kernel(dl_ref, ml_ref, dr_ref, mr_ref, dist_ref, idx_ref, *,
@@ -66,8 +76,8 @@ def _kernel(dl_ref, ml_ref, dr_ref, mr_ref, dist_ref, idx_ref, *,
         dist_ref[...] = jnp.full_like(dist_ref, BIG)
         idx_ref[...] = jnp.full_like(idx_ref, -1)
 
-    dist = masked_hamming(dl_ref[...], ml_ref[...], dr_ref[...],
-                          mr_ref[...], row_band=row_band,
+    dist = masked_hamming(dl_ref[...], ml_ref[...], dr_ref[...].T,
+                          mr_ref[...].T, row_band=row_band,
                           max_disparity=max_disparity)
 
     # Compare: running argmin against the accumulated best.

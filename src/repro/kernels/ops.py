@@ -2,9 +2,9 @@
 
 Every op takes ``impl``:
   - "ref"     — pure-jnp oracle (ref.py), any backend.
-  - "pallas"  — Pallas kernel; on CPU it automatically runs in
-                interpret mode (the kernel body executed in Python),
-                on TPU it compiles to Mosaic.
+  - "pallas"  — Pallas kernel: compiled by Mosaic on the TPU; on the
+                CPU (tests) it runs in interpret mode; any other
+                backend raises.
   - None      — the innermost ``use_impl`` context, else the process
                 default (``set_default_impl`` / REPRO_KERNEL_IMPL env
                 var), else "ref" on CPU and "pallas" on TPU.  Sessions
@@ -32,12 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import pattern as _pattern
 from repro.kernels import ref as _ref
-from repro.kernels.describe_fused import (KP_BLOCK, _cast_slab,
-                                          describe_fused_pallas,
-                                          describe_fused_pyramid_pallas,
-                                          orient_fused_pallas)
+from repro.kernels.describe_fused import (KP_BLOCK, WIN_H, WIN_W,
+                                          _cast_slab,
+                                          describe_fused_pyramid_pallas)
 from repro.kernels.fast_detect import (HALO, TILE_H, TILE_W,
                                        fast_score_map_pallas)
 from repro.kernels.frontend_fused import (FUSED_HALO, fast_score_from_taps,
@@ -45,8 +43,8 @@ from repro.kernels.frontend_fused import (FUSED_HALO, fast_score_from_taps,
                                           frontend_fused_pyramid_pallas)
 from repro.kernels.gaussian_blur import gaussian_blur7_pallas
 from repro.kernels.hamming_match import BIG, BK, hamming_match_pallas
-from repro.kernels.matcher_fused import (FM_BK, FM_BM, MO_BK,
-                                         match_fused_pallas,
+from repro.kernels.matcher_fused import (FM_BK, FM_BM, MO_BK, SAD_WIN_H,
+                                         SAD_WIN_W, match_fused_pallas,
                                          match_rectify_fused_pallas,
                                          sad_fused_pallas)
 from repro.kernels.sad_rectify import sad_search_pallas
@@ -163,7 +161,15 @@ def _count_launches(n: int = 1) -> None:
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Mosaic on the TPU, interpret mode on the CPU (the test backend).
+    Any other backend has no Pallas path here and raises rather than
+    silently interpreting."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"impl='pallas' runs on 'tpu' (Mosaic) or 'cpu' (interpret "
+            f"mode, for tests); the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_tiles(img: jnp.ndarray, halo: int, th: int, tw: int):
@@ -415,50 +421,42 @@ def _orient_describe_jnp(raw, smoothed, xy):
     return jax.vmap(_ref.orient_describe)(raw, smoothed, xy)
 
 
-def _pad_patch_slab(imgs: jnp.ndarray) -> jnp.ndarray:
-    """Edge-pad a (B, H, W) batch by the 31x31 patch RADIUS, plus
-    edge-replicated tile alignment (Hp % 8 == Wp % 128 == 0).  Clamped
-    patch starts never reach the alignment region."""
-    _, h, w = imgs.shape
-    r = _ref.RADIUS
-    hp = (-(h + 2 * r)) % 8
-    wp = (-(w + 2 * r)) % 128
-    return jnp.pad(_cast_slab(imgs),
-                   ((0, 0), (r, r + hp), (r, r + wp)), mode="edge")
+def _describe_canvas(shapes) -> tuple[int, int]:
+    """Common (Hc, Wc) slab canvas of the sparse kernel: room for every
+    level's RADIUS-padded slab and for the tile-aligned
+    (WIN_H, WIN_W) window around any clamped patch start."""
+    hc = max((h - 1) // 8 * 8 + WIN_H for h, _ in shapes)
+    wc = max((w - 1) // 128 * 128 + WIN_W for _, w in shapes)
+    return hc, wc
+
+
+def _resolve_steering(mom, bins, desc16):
+    """Finish the sparse kernel's outputs: theta = atan2(m01, m10) (the
+    oracle's own XLA op, so bit-equal to ``ref.patch_theta``), and the
+    descriptor of whichever candidate bin equals the oracle's bin."""
+    theta = jnp.arctan2(mom[..., 1], mom[..., 0])
+    hi = (_ref.theta_to_bin(theta) == bins[..., 1])[..., None]
+    return theta, mom, jnp.where(hi, desc16[..., 8:], desc16[..., :8])
 
 
 def orient_describe_batched(raw: jnp.ndarray, smoothed: jnp.ndarray | None,
                             xy: jnp.ndarray, *, impl: str | None = None):
-    """Fused batched sparse stage: orientation + moments + rBRIEF for a
-    (B, K) block of keypoints in ONE kernel launch.
+    """Batched sparse stage of ONE pyramid level: orientation + moments
+    + rBRIEF for a (B, K) block of keypoints in ONE kernel launch.
 
     raw/smoothed: (B, H, W) level images (smoothed = 7x7 Gaussian blur;
-    None selects the orientation-only kernel — ``fast.detect``'s path);
-    xy: (B, K, 2) int32 level coords (clamped into the image, so top-K
+    None returns orientation only — ``fast.detect``'s path); xy:
+    (B, K, 2) int32 level coords (clamped into the image, so top-K
     padding rows with ``valid=False`` are safe).  Returns (theta (B, K)
     float32, moments (B, K, 2) float32, desc (B, K, 8) uint32 or None).
-
-    B is the flattened camera batch of a pyramid level: together with
-    ``fast_blur_nms_batched`` this makes the frontend exactly TWO
-    launches per level (dense + sparse) for all cameras.  The wrapper
-    owns K-padding to KP_BLOCK multiples and the patch-halo image pad.
+    The Pallas path is the whole-frame kernel over one level.
     """
-    _, h, w = raw.shape
-    k = xy.shape[1]
     if resolve_impl(impl) == "ref":
         return _orient_describe_jnp(raw, smoothed, xy)
-    kp = (-k) % KP_BLOCK
-    xy_p = jnp.pad(xy.astype(jnp.int32), ((0, 0), (0, kp), (0, 0)))
-    raw_p = _pad_patch_slab(raw)
-    _count_launches()
-    if smoothed is None:
-        theta, mom = orient_fused_pallas(raw_p, xy_p, true_h=h, true_w=w,
-                                         interpret=_interpret())
-        return theta[:, :k], mom[:, :k], None
-    theta, mom, desc = describe_fused_pallas(
-        jnp.asarray(_pattern.STEER_LUT), raw_p, _pad_patch_slab(smoothed),
-        xy_p, true_h=h, true_w=w, interpret=_interpret())
-    return theta[:, :k], mom[:, :k], desc[:, :k]
+    sm = raw if smoothed is None else smoothed
+    (theta, mom, desc), = orient_describe_pyramid([raw], [sm], [xy],
+                                                  impl="pallas")
+    return theta, mom, None if smoothed is None else desc
 
 
 def orient_describe_pyramid(raws, smootheds, xys, *,
@@ -483,18 +481,13 @@ def orient_describe_pyramid(raws, smootheds, xys, *,
         return [_orient_describe_jnp(r, s, xy)
                 for r, s, xy in zip(raws, smootheds, xys)]
     shapes = [(int(r_.shape[1]), int(r_.shape[2])) for r_ in raws]
-    b = raws[0].shape[0]
     rad = _ref.RADIUS
-    hc = max(h for h, _ in shapes) + 2 * rad
-    hc += (-hc) % 8
-    wc = max(w for _, w in shapes) + 2 * rad
-    wc += (-wc) % 128
+    hc, wc = _describe_canvas(shapes)
 
     def slab(imgs, h, w):
         # Per-level edge pad by the patch RADIUS, then edge-replicated
-        # out to the common canvas; clamped patch starts stay within the
-        # (h + 2*rad, w + 2*rad) region, so the canvas pad is never read
-        # with values differing from the per-level slab.
+        # out to the common canvas; a patch never reads past the
+        # (h + 2*rad, w + 2*rad) region.
         return jnp.pad(_cast_slab(imgs),
                        ((0, 0), (rad, hc - h - rad), (rad, wc - w - rad)),
                        mode="edge")
@@ -512,9 +505,9 @@ def orient_describe_pyramid(raws, smootheds, xys, *,
     offsets = tuple(int(o) for o in np.cumsum([0] + nbs[:-1]))
     hw = jnp.asarray(np.repeat(np.asarray(shapes, np.int32), nbs, axis=0))
     _count_launches()
-    theta, mom, desc = describe_fused_pyramid_pallas(
-        jnp.asarray(_pattern.STEER_LUT), raw_all, sm_all, xy_all, hw,
-        level_offsets=offsets, interpret=_interpret())
+    theta, mom, desc = _resolve_steering(*describe_fused_pyramid_pallas(
+        raw_all, sm_all, xy_all, hw, level_offsets=offsets,
+        interpret=_interpret()))
     outs, off = [], 0
     for k, kp in zip(ks, kps):
         outs.append((theta[:, off:off + k], mom[:, off:off + k],
@@ -600,14 +593,34 @@ def _pad_axis1(x: jnp.ndarray, mult: int):
 
 
 def _pad_fm_slab(imgs: jnp.ndarray, ry: int, rx: int) -> jnp.ndarray:
-    """Edge-pad a (P, H, W) pair batch by the FM patch radii, plus
-    edge-replicated tile alignment (Hp % 8 == Wp % 128 == 0).  Clamped
-    patch starts never reach the alignment region."""
+    """Edge-pad a (P, H, W) pair batch by the FM patch radii, then
+    edge-replicate out to room for the tile-aligned
+    (SAD_WIN_H, SAD_WIN_W) window around any clamped patch start
+    (Hp % 8 == Wp % 128 == 0).  The alignment region is never read into
+    a patch."""
     _, h, w = imgs.shape
-    hp = (-(h + 2 * ry)) % 8
-    wp = (-(w + 2 * rx)) % 128
+    hp = max(h + 2 * ry + (-(h + 2 * ry)) % 8,
+             (h - 1) // 8 * 8 + SAD_WIN_H)
+    wp = max(w + 2 * rx + (-(w + 2 * rx)) % 128,
+             (w - 1) // 128 * 128 + SAD_WIN_W)
     return jnp.pad(_cast_slab(imgs),
-                   ((0, 0), (ry, ry + hp), (rx, rx + wp)), mode="edge")
+                   ((0, 0), (ry, hp - h - ry), (rx, wp - w - rx)),
+                   mode="edge")
+
+
+def _check_sad_window(sad_window: int, sad_range: int) -> None:
+    if sad_window + 7 > SAD_WIN_H or \
+            sad_window + 2 * sad_range + 127 > SAD_WIN_W:
+        raise ValueError(
+            f"sad_window={sad_window}, sad_range={sad_range} do not fit "
+            f"the kernel's aligned ({SAD_WIN_H}, {SAD_WIN_W}) window")
+
+
+def _right_t(desc_r: jnp.ndarray, meta_r: jnp.ndarray):
+    """Pad the right side to FM_BM rows and transpose it to the
+    kernels' lane-dense (P, 8, M) / (P, 4, M) layout."""
+    return (jnp.swapaxes(_pad_axis1(desc_r, FM_BM), 1, 2),
+            jnp.swapaxes(_pad_axis1(meta_r, FM_BM), 1, 2))
 
 
 def _match_rectify_jnp(dl, ml, dr, mr, il, ir, row_band, max_disparity,
@@ -673,28 +686,28 @@ def match_rectify_fused(desc_l: jnp.ndarray, meta_l: jnp.ndarray,
     bk = MO_BK if match_only else FM_BK
     dl = _pad_axis1(desc_l, bk)
     ml = _pad_axis1(meta_l, bk)
-    dr = _pad_axis1(desc_r, FM_BM)
-    mr = _pad_axis1(meta_r, FM_BM)
+    dr, mr = _right_t(desc_r, meta_r)
     _count_launches()
     if match_only:
         dist, idx = match_fused_pallas(
             dl, ml, dr, mr, row_band=float(row_band),
             max_disparity=float(max_disparity), interpret=_interpret())
-        dist, idx = dist[:, :k], idx[:, :k]
+        dist, idx = dist[:, :k, 0], idx[:, :k, 0]
         return dist, jnp.where(dist >= BIG, -1, idx)
+    _check_sad_window(sad_window, sad_range)
     _, h, w = img_l.shape
     ry = sad_window // 2
     dist, idx, rxy, sad = match_rectify_fused_pallas(
-        dl, ml, dr, mr, meta_r[:, 0, :2],
+        dl, ml, dr, mr, meta_r[:, 0:1, :2],
         _pad_fm_slab(img_l, ry, ry),
         _pad_fm_slab(img_r, ry, ry + sad_range),
         row_band=float(row_band), max_disparity=float(max_disparity),
         max_hamming=int(max_hamming), patch=int(sad_window),
         sad_range=int(sad_range), true_h=h, true_w=w,
         interpret=_interpret())
-    dist, idx = dist[:, :k], idx[:, :k]
+    dist, idx = dist[:, :k, 0], idx[:, :k, 0]
     return (dist, jnp.where(dist >= BIG, -1, idx), rxy[:, :k],
-            sad[:, :k])
+            sad[:, :k, 0])
 
 
 def sad_patch_search(img_l: jnp.ndarray, img_r: jnp.ndarray,
@@ -716,6 +729,7 @@ def sad_patch_search(img_l: jnp.ndarray, img_r: jnp.ndarray,
                 _ref.gather_patches(ir, xr, sad_window,
                                     sad_window + 2 * sad_range))
         )(img_l, img_r, xy_l, xy_r)
+    _check_sad_window(sad_window, sad_range)
     k = xy_l.shape[1]
     _, h, w = img_l.shape
     ry = sad_window // 2

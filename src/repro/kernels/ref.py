@@ -378,7 +378,7 @@ def orient_describe(raw: jnp.ndarray, smoothed: jnp.ndarray,
     raw/smoothed: (H, W) float32 level image and its 7x7-Gaussian blur;
     xy: (K, 2) int32 level coords.  Returns (theta (K,), moments (K, 2),
     desc (K, 8) uint32) — exactly the three outputs
-    ``describe_fused_pallas`` emits per (camera, K-block) grid step.
+    ``ops.orient_describe_batched`` returns for one image.
     """
     theta, mom = patch_theta(extract_patches(raw, xy))
     desc = lut_descriptor(extract_patches(smoothed, xy),

@@ -30,13 +30,13 @@ from repro.core.types import (LocalizationOutput, LocalizationState,
                               PoseSet)
 from repro.localization import geometry, metrics, pose
 from repro.localization.geometry import rig_points
-from repro.localization.metrics import trajectory_metrics
+from repro.localization.metrics import ACCURACY_LIMITS, trajectory_metrics
 from repro.localization.pose import (MIN_CORRESPONDENCES, solve_pose,
                                      solve_pose_batched)
 
 __all__ = [
     "geometry", "metrics", "pose",
-    "rig_points", "trajectory_metrics",
+    "rig_points", "trajectory_metrics", "ACCURACY_LIMITS",
     "MIN_CORRESPONDENCES", "solve_pose", "solve_pose_batched",
     "PoseSet", "LocalizationOutput", "LocalizationState",
     "state_from", "zero_state",

@@ -13,6 +13,7 @@ ZERO kernel launches and batches over arbitrary leading axes
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,7 +52,10 @@ def rig_points(xy: jnp.ndarray, depth: jnp.ndarray,
     cy = jnp.asarray([ic.cy for ic in intr], jnp.float32)[:, None]
     cam = backproject(xy, depth, fx, fy, cx, cy)
     rot = jnp.asarray(rig.pair_rotation_array())
-    return jnp.einsum("pji,...pki->...pkj", rot, cam)
+    # HIGHEST: a default f32 einsum on the TPU runs in bf16 passes,
+    # which would round the points (and so the pose) to ~3 digits.
+    return jnp.einsum("pji,...pki->...pkj", rot, cam,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def gt_relative_pose(r_prev: np.ndarray, t_prev: np.ndarray,

@@ -19,6 +19,19 @@ from __future__ import annotations
 
 import numpy as np
 
+# The accuracy gates of a localized run over the constant-twist scene:
+# gate key -> (``trajectory_metrics`` key, limit, unit).  Pinned at ~2x
+# the worst measured CPU baseline across scene sizes and the f32/uint8
+# datapaths (ATE 0.19-0.29 m, RPE-t 0.10 m, RPE-r 0.10-0.14 deg) —
+# tight enough to catch a solver or matcher regression, loose enough
+# to absorb accelerator reduction-order jitter.  Read by the benchmark
+# and by the chip smoke run.
+ACCURACY_LIMITS = {
+    "ate": ("ate_rmse_m", 0.60, "m"),
+    "rpe_trans": ("rpe_trans_rmse_m", 0.25, "m"),
+    "rpe_rot": ("rpe_rot_mean_deg", 0.30, "deg"),
+}
+
 
 def _as_np(x) -> np.ndarray:
     return np.asarray(x, dtype=np.float64)

@@ -82,7 +82,11 @@ def solve_pose(pts_prev: jnp.ndarray, pts_curr: jnp.ndarray,
 
     def round_(w_c, _):
         r_c, t_c = backend.kabsch(pts_prev, pts_curr, w_c)
-        res = jnp.linalg.norm(pts_prev @ r_c.T + t_c - pts_curr, axis=-1)
+        # f32 residuals: a default f32 matmul on the TPU runs in bf16
+        # passes, which would reorder the inlier ranking.
+        moved = jnp.matmul(pts_prev, r_c.T,
+                           precision=jax.lax.Precision.HIGHEST)
+        res = jnp.linalg.norm(moved + t_c - pts_curr, axis=-1)
         n = jnp.sum((w_c > 0).astype(jnp.int32))
         keep = jnp.maximum(jnp.int32(min_corr),
                            jnp.ceil(keep_frac * n).astype(jnp.int32))

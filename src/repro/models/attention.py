@@ -236,7 +236,7 @@ def write_slot(cache, tok, slot, li=None):
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as Ps
 
     from repro.distributed.sharding import current_ctx, resolve
@@ -278,7 +278,7 @@ def write_slot(cache, tok, slot, li=None):
 
     @partial(shard_map, mesh=ctx.mesh,
              in_specs=(spec, tok_spec, Ps(), Ps()),
-             out_specs=spec, check_rep=False)
+             out_specs=spec, check_vma=False)
     def write(c_loc, t_loc, slot_, li_):
         sid = 0
         for a in mesh_axes:

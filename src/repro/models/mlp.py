@@ -107,7 +107,7 @@ def moe_a2a(params, x: jnp.ndarray, s: MoESpec):
     """
     import functools
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as Ps
 
     from repro.distributed.sharding import current_ctx, resolve
@@ -147,7 +147,7 @@ def moe_a2a(params, x: jnp.ndarray, s: MoESpec):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(x_spec, r_spec, wi_spec, wi_spec, wo_spec),
-        out_specs=(x_spec, Ps()), check_rep=False)
+        out_specs=(x_spec, Ps()), check_vma=False)
     def run(x_l, router_l, wg_l, wu_l, wo_l):
         b_l, s_l, d = x_l.shape
         t_l = b_l * s_l

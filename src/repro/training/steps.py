@@ -17,7 +17,7 @@ import functools
 from typing import NamedTuple
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as Ps
 
 from repro.configs.base import ModelConfig
@@ -62,7 +62,7 @@ def make_compressed_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
         @functools.partial(shard_map, mesh=mesh,
                            in_specs=(p_spec, b_spec),
                            out_specs=(p_spec, Ps()),
-                           check_rep=False)
+                           check_vma=False)
         def local_grads(params, local_batch):
             (loss, metrics), grads = jax.value_and_grad(
                 lambda p: lm.loss_fn(p, cfg, local_batch),

@@ -173,14 +173,16 @@ def test_oversized_block_fails_the_budget():
 
 
 def test_unblocked_halo_counted_in_block_bytes():
-    """frontend_fused loads (1, T+8, T+8) halo windows via Unblocked —
-    residency must charge the halo'd block, not the 128x128 tile."""
+    """frontend_fused loads (1, T+8, W+8) halo'd row bands via Element
+    blocks — residency must charge the halo'd band, not the 128x128
+    tile."""
     te = _trace("frame_f32")
     halo = [b for s in te.sites
             for b in analysis.launch_vmem(s).blocks
-            if b.mode == "Unblocked"]
+            if b.mode == "Element"]
     assert halo
-    assert any(b.block_shape[-1] == 136 for b in halo)
+    assert any(b.block_shape[-2] == 136 and b.block_shape[-1] == W + 8
+               for b in halo)
 
 
 # ---------------------------------------------------------------------------
@@ -254,10 +256,8 @@ def test_blocked_index_map_off_by_one_is_caught():
 
 
 def test_unblocked_window_escaping_slab_is_caught():
-    bad = pl.BlockSpec((6,), lambda i: (i * 4,),
-                       indexing_mode=pl.Unblocked())
-    out = pl.BlockSpec((6,), lambda i: (0,),
-                       indexing_mode=pl.Unblocked())
+    bad = pl.BlockSpec((pl.Element(6),), lambda i: (i * 4,))
+    out = pl.BlockSpec((pl.Element(6),), lambda i: (0,))
     out_shape = jax.ShapeDtypeStruct((6,), jnp.float32)
     closed, (site,) = _sites_of(
         lambda x: pl.pallas_call(

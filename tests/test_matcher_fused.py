@@ -36,6 +36,13 @@ except ImportError:          # dev-only dep; property tests skip
     HAVE_HYPOTHESIS = False
 
 
+# Both FM schedules as one jitted program per (shape, config) instead
+# of op-by-op dispatch: these sweeps are dominated by compilation.
+_STATIC = ("cfg", "intr", "impl")
+_fused = jax.jit(match_pair_fused, static_argnames=_STATIC)
+_unfused = jax.jit(match_pair_unfused, static_argnames=_STATIC)
+
+
 def _system(cfg, intr=None, impl=None):
     intr = intr if intr is not None else CameraIntrinsics()
     return VisualSystem(RigConfig.stereo(intr),
@@ -100,11 +107,11 @@ def test_fused_matches_unfused_bitexact(h, w, k, m, n_pairs):
                     max_hamming=140)
     intr = CameraIntrinsics(fx=120.0, cx=w / 2.0, cy=h / 2.0,
                             baseline=0.2)
-    want = [match_pair_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
+    want = [_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
                                cfg, intr, impl="ref")
             for p in range(n_pairs)]
     for impl in ("ref", "pallas"):
-        mf, df = match_pair_fused(imgs_l, imgs_r, _stack_feats(fls),
+        mf, df = _fused(imgs_l, imgs_r, _stack_feats(fls),
                                   _stack_feats(frs), cfg, intr,
                                   impl=impl)
         _assert_pair_equal(mf, [wm for wm, _ in want], f"{impl} match")
@@ -123,10 +130,10 @@ def test_fused_all_invalid_features():
                                             valid_frac=0.0)
     cfg = ORBConfig(height=64, width=96, max_disparity=64)
     intr = CameraIntrinsics(cx=48.0, cy=32.0)
-    want = [match_pair_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
+    want = [_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
                                cfg, intr, impl="ref") for p in range(2)]
     for impl in ("ref", "pallas"):
-        mf, df = match_pair_fused(imgs_l, imgs_r, _stack_feats(fls),
+        mf, df = _fused(imgs_l, imgs_r, _stack_feats(fls),
                                   _stack_feats(frs), cfg, intr,
                                   impl=impl)
         assert int(mf.valid.sum()) == 0
@@ -362,10 +369,10 @@ if HAVE_HYPOTHESIS:
                         max_hamming=160)
         intr = CameraIntrinsics(fx=90.0, cx=w / 2.0, cy=h / 2.0,
                                 baseline=0.15)
-        mf, df = match_pair_fused(imgs_l, imgs_r, _stack_feats(fls),
+        mf, df = _fused(imgs_l, imgs_r, _stack_feats(fls),
                                   _stack_feats(frs), cfg, intr,
                                   impl="ref")
-        want = [match_pair_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
+        want = [_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
                                    cfg, intr, impl="ref")
                 for p in range(n_pairs)]
         _assert_pair_equal(mf, [wm for wm, _ in want],
@@ -385,10 +392,10 @@ if HAVE_HYPOTHESIS:
                         max_hamming=180)
         intr = CameraIntrinsics(fx=90.0, cx=w / 2.0, cy=h / 2.0,
                                 baseline=0.15)
-        mf, df = match_pair_fused(imgs_l, imgs_r, _stack_feats(fls),
+        mf, df = _fused(imgs_l, imgs_r, _stack_feats(fls),
                                   _stack_feats(frs), cfg, intr,
                                   impl="pallas")
-        want = [match_pair_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
+        want = [_unfused(imgs_l[p], imgs_r[p], fls[p], frs[p],
                                    cfg, intr, impl="ref")
                 for p in range(n_pairs)]
         _assert_pair_equal(mf, [wm for wm, _ in want],

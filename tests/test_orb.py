@@ -1,5 +1,6 @@
 """Behavioural tests of the ORB extraction stages (paper Sec. II-B)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -61,6 +62,29 @@ def test_topk_respects_border_and_static_shape():
     v = np.asarray(valid)
     assert np.all(xs[v] >= 16) and np.all(xs[v] < 48)
     assert np.all(ys[v] >= 16) and np.all(ys[v] < 48)
+
+
+@pytest.mark.parametrize("batch", [1, 4, 16])
+def test_topk_tie_order_is_score_then_lowest_index(batch):
+    """FAST scores are small integers, so ties are the rule: the kept
+    corners and their order must be (score descending, flat index
+    ascending) for every camera whatever the batch size — the contract
+    the TPU's chunked top-K lowering does not keep by itself."""
+    rng = np.random.RandomState(batch)
+    h, w, k, border = 40, 56, 150, 3
+    scores = rng.randint(0, 4, (batch, h, w)).astype(np.float32)
+    xy, vals, valid = jax.vmap(
+        lambda s: fast.select_topk(s, k, border))(jnp.asarray(scores))
+    for b in range(batch):
+        masked = np.zeros((h, w), np.float32)
+        masked[border:h - border, border:w - border] = \
+            scores[b, border:h - border, border:w - border]
+        flat = masked.reshape(-1)
+        want = np.lexsort((np.arange(flat.size), -flat))[:k]
+        np.testing.assert_array_equal(np.asarray(xy[b]),
+                                      np.stack([want % w, want // w], -1))
+        np.testing.assert_array_equal(np.asarray(vals[b]), flat[want])
+        np.testing.assert_array_equal(np.asarray(valid[b]), flat[want] > 0)
 
 
 def test_orientation_points_toward_bright_side():

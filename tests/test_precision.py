@@ -47,6 +47,12 @@ from repro.serving import wire_decode, wire_encode  # noqa: E402
 
 _SETTINGS = dict(max_examples=15, deadline=None)
 
+# One jitted program per (shape, config) instead of op-by-op dispatch:
+# the same computation, compiled once per example rather than once per
+# jnp op — the sweeps below are dominated by compilation.
+_extract = jax.jit(extract_features_batched,
+                   static_argnames=("cfg", "impl", "precision"))
+
 
 def _imgs_u8(seed, b, h, w):
     rng = np.random.RandomState(seed % (2 ** 31))
@@ -76,10 +82,8 @@ def _assert_bitexact(fu, ff, msg):
 def _check_u8_equals_f32(b, h, w, n_levels, thr, seed, impl):
     imgs = _imgs_u8(seed, b, h, w)
     cfg = _cfg(h, w, n_levels, thr)
-    fu = extract_features_batched(jnp.asarray(imgs), cfg, impl=impl,
-                                  precision="uint8")
-    ff = extract_features_batched(jnp.asarray(imgs.astype(np.float32)),
-                                  cfg, impl=impl)
+    fu = _extract(jnp.asarray(imgs), cfg, impl=impl, precision="uint8")
+    ff = _extract(jnp.asarray(imgs.astype(np.float32)), cfg, impl=impl)
     for i in range(b):
         assert ref.keypoint_set_diff(fu.xy[i], fu.valid[i],
                                      ff.xy[i], ff.valid[i]) == 0
@@ -166,10 +170,10 @@ def test_u8_vs_unquantized_oracle_bounded():
         imgs = _imgs_u8(seed, 2, h, w)
         cfg_q = _cfg(h, w, 3)
         cfg_u = dataclasses.replace(cfg_q, quantized=False)
-        fu = extract_features_batched(jnp.asarray(imgs), cfg_q,
-                                      impl="ref", precision="uint8")
-        ff = extract_features_batched(
-            jnp.asarray(imgs.astype(np.float32)), cfg_u, impl="ref")
+        fu = _extract(jnp.asarray(imgs), cfg_q, impl="ref",
+                      precision="uint8")
+        ff = _extract(jnp.asarray(imgs.astype(np.float32)), cfg_u,
+                      impl="ref")
         for i in range(2):
             mean, _ = ref.descriptor_hamming_stats(
                 fu.desc[i], ff.desc[i], fu.valid[i] & ff.valid[i])

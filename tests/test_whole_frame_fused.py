@@ -32,6 +32,14 @@ except ImportError:          # dev-only dep; property tests skip
     HAVE_HYPOTHESIS = False
 
 
+# Both schedules as one jitted program per (shape, config), as a session
+# runs them, instead of op-by-op dispatch: the sweeps below are
+# dominated by compilation.
+_STATIC = ("cfg", "impl")
+_whole = jax.jit(extract_features_batched, static_argnames=_STATIC)
+_per_level = jax.jit(extract_features_per_level, static_argnames=_STATIC)
+
+
 def _imgs(seed, b, h, w):
     rng = np.random.RandomState(seed)
     return jnp.asarray(rng.randint(0, 256, (b, h, w)).astype(np.float32))
@@ -176,8 +184,8 @@ def test_whole_frame_extractor_equals_per_level_ref(b, shape, n_levels):
     imgs = _imgs(7, b, *shape)
     cfg = ORBConfig(height=shape[0], width=shape[1], max_features=48,
                     n_levels=n_levels)
-    whole = extract_features_batched(imgs, cfg, impl="ref")
-    per = extract_features_per_level(imgs, cfg, impl="ref")
+    whole = _whole(imgs, cfg, impl="ref")
+    per = _per_level(imgs, cfg, impl="ref")
     _assert_featureset_equal(whole, per, f"ref b={b} {shape} L={n_levels}")
 
 
@@ -189,11 +197,10 @@ def test_whole_frame_extractor_equals_per_level_pallas(b, shape, n_levels):
     imgs = _imgs(8, b, *shape)
     cfg = ORBConfig(height=shape[0], width=shape[1], max_features=32,
                     n_levels=n_levels)
-    whole = extract_features_batched(imgs, cfg, impl="pallas")
-    per = extract_features_per_level(imgs, cfg, impl="pallas")
+    whole = _whole(imgs, cfg, impl="pallas")
+    per = _per_level(imgs, cfg, impl="pallas")
     _assert_featureset_equal(whole, per, "pallas whole vs per-level")
-    _assert_featureset_equal(whole,
-                             extract_features_batched(imgs, cfg, impl="ref"),
+    _assert_featureset_equal(whole, _whole(imgs, cfg, impl="ref"),
                              "pallas vs ref")
 
 
@@ -287,8 +294,8 @@ if HAVE_HYPOTHESIS:
         imgs = _imgs(seed, b, h, w)
         cfg = ORBConfig(height=h, width=w, max_features=24,
                         n_levels=n_levels, fast_threshold=int(thr))
-        whole = extract_features_batched(imgs, cfg, impl="ref")
-        per = extract_features_per_level(imgs, cfg, impl="ref")
+        whole = _whole(imgs, cfg, impl="ref")
+        per = _per_level(imgs, cfg, impl="ref")
         _assert_featureset_equal(whole, per,
                                  f"b={b} {h}x{w} L={n_levels} thr={thr}")
 
