@@ -26,6 +26,7 @@ from repro.kernels.ref import nms3  # noqa: F401  (oracle; back-compat export)
 from repro.kernels.ref import PATCH, RADIUS  # noqa: F401
 
 
+@jax.named_scope("select_topk")
 def select_topk(score: jnp.ndarray, k: int, border: int):
     """Top-K corners of a score map. Returns (xy (K,2) int32, score (K,),
     valid (K,) bool), ordered by score descending, ties by the lower
@@ -37,7 +38,7 @@ def select_topk(score: jnp.ndarray, k: int, border: int):
     order that changed with the batch size.  Here top-K only supplies
     the K-th value; which of the tied corners at that value are kept
     (the lowest indices), and the order of the K, are decided
-    explicitly."""
+    explicitly.  Its device ops carry the ``select_topk`` scope."""
     h, w = score.shape
     row = jnp.arange(h)[:, None]
     col = jnp.arange(w)[None, :]

@@ -89,21 +89,24 @@ def extract_features_batched(images: jnp.ndarray, cfg: ORBConfig,
     levels = pyramid.build_pyramid_batched(images, cfg,
                                            precision=precision)
     ks = cfg.features_per_level()
-    dense = ops.fast_blur_nms_pyramid(
-        levels, float(cfg.fast_threshold), nms=cfg.nms,
-        quantized=cfg.quantized, impl=impl)
+    with jax.named_scope("dense_fe"):
+        dense = ops.fast_blur_nms_pyramid(
+            levels, float(cfg.fast_threshold), nms=cfg.nms,
+            quantized=cfg.quantized, impl=impl)
     topk = []
     for (_smoothed, score), k_l in zip(dense, ks):
         topk.append(jax.vmap(
             lambda s, k=k_l: fast.select_topk(s, k, cfg.border))(score))
-    sparse = ops.orient_describe_pyramid(
-        levels, [sm for sm, _ in dense], [xy for xy, _, _ in topk],
-        impl=impl)
-    parts = []
-    for lvl, ((xy, vals, valid), (theta, _mom, desc)) in enumerate(
-            zip(topk, sparse)):
-        parts.append(_level_features(lvl, cfg, xy, vals, valid, theta, desc))
-    return _merge_levels(parts)
+    with jax.named_scope("describe"):
+        sparse = ops.orient_describe_pyramid(
+            levels, [sm for sm, _ in dense], [xy for xy, _, _ in topk],
+            impl=impl)
+        parts = []
+        for lvl, ((xy, vals, valid), (theta, _mom, desc)) in enumerate(
+                zip(topk, sparse)):
+            parts.append(_level_features(lvl, cfg, xy, vals, valid, theta,
+                                         desc))
+        return _merge_levels(parts)
 
 
 def extract_features_per_level(images: jnp.ndarray, cfg: ORBConfig,

@@ -174,6 +174,15 @@ class VisualSystem:
     validates frame shapes eagerly with clear errors, and applies the
     rig's sync policy to per-frame time tags (``desync_log`` /
     ``DesyncError``).
+
+    ``counters`` counts what the session did: ``traces.<entry>`` (jit
+    traces of an entry's program) and ``calls.process_frame``.
+    ``process_frame`` records profiler spans: ``repro.process_frame``
+    (with ``call``, the call number) around ``repro.validate``,
+    ``repro.frame_call`` (with ``h2d_bytes``: the bytes of a host NumPy
+    frame handed to the program, 0 for a device array) and
+    ``repro.localize_call``; with no profiler running a span costs one
+    check.
     """
 
     def __init__(self, rig: RigConfig,
@@ -191,7 +200,7 @@ class VisualSystem:
         # context or global flips cannot silently miss the jit cache.
         self.impl: str = ops.resolve_impl(self.pipe.impl)
         self._jitted: dict = {}
-        self._trace_counts: dict = {}
+        self.counters: collections.Counter = collections.Counter()
         # Bounded health log: one spread per checked frame; a streaming
         # session at 30 fps would otherwise grow this without limit.
         self.desync_log: "collections.deque[float]" = collections.deque(
@@ -204,22 +213,46 @@ class VisualSystem:
 
     # -- jit cache ---------------------------------------------------------
 
+    @staticmethod
+    def _entry(key) -> str:
+        """The entry name of a jit-cache key: the key, or its first
+        element for keys that carry static parameters."""
+        return key if isinstance(key, str) else key[0]
+
     def _jit(self, key, fn):
         """Jit ``fn`` once per entry-point key; jax.jit's own cache then
-        keys on argument shapes.  The wrapper counts traces (a python
-        side effect that only fires while tracing) so tests can assert
-        repeated same-shape calls retrace zero times."""
+        keys on argument shapes.  The program is named after the entry
+        (HLO module ``jit_<entry>``).  The wrapper counts traces (a
+        python side effect that only fires while tracing) so tests can
+        assert repeated same-shape calls retrace zero times."""
         if key not in self._jitted:
+            entry = self._entry(key)
+
             def counted(*args):
-                self._trace_counts[key] = self._trace_counts.get(key, 0) + 1
+                self.counters[f"traces.{entry}"] += 1
                 return fn(*args)
+            counted.__name__ = counted.__qualname__ = entry
             self._jitted[key] = jax.jit(counted)
+        return self._jitted[key]
+
+    def program(self, key):
+        """The jitted program of entry ``key``, once an entry point has
+        built it.  A TPU profiler trace names each device op by its HLO
+        instruction alone, without the ``jax.named_scope`` path; the
+        ``op_name`` metadata of
+        ``program(key).lower(*args).compile().as_text()`` carries it,
+        so a trace reader maps ops to scopes through this text."""
         return self._jitted[key]
 
     def trace_count(self, key) -> int:
         """How many times entry point ``key`` has been traced (i.e. how
         many distinct input shapes it has compiled for)."""
-        return self._trace_counts.get(key, 0)
+        return self.counters[f"traces.{self._entry(key)}"]
+
+    @staticmethod
+    def _span(name: str, **stats):
+        """A ``repro.<name>`` profiler span carrying ``stats``."""
+        return jax.profiler.TraceAnnotation(f"repro.{name}", **stats)
 
     # -- shape / sync validation (eager, outside jit) ----------------------
 
@@ -362,6 +395,7 @@ class VisualSystem:
         feat_r = jax.tree.map(lambda x: x[ri], feats)
         return images[li], images[ri], feat_l, feat_r
 
+    @jax.named_scope("stereo")
     def _fm_flat(self, carry, n_rigs: int, impl) -> StereoOutput:
         """FM stage over the flat pair batch: ONE fused matcher launch
         whose grid folds every pair of every rig."""
@@ -477,6 +511,7 @@ class VisualSystem:
               else float(self.pipe.temporal_radius_y))
         return rx, ry
 
+    @jax.named_scope("localize")
     def _loc_flat(self, out: StereoOutput, prev: LocalizationState,
                   n_rigs: int, impl):
         """Backend stage over the FLAT (n_rigs * n_pairs,) pair batch:
@@ -496,12 +531,14 @@ class VisualSystem:
             points=pts_flat,
             valid=out.features_l.valid & out.depth.valid)
         rx, ry = self._temporal_radii()
-        pp, cp, w = pose_solver.temporal_correspondences(
-            prev, curr, self.pipe.orb, rx, ry, impl)
-        pose = pose_solver.solve_pose_batched(
-            pp.reshape((n_rigs, p * k, 3)),
-            cp.reshape((n_rigs, p * k, 3)),
-            w.reshape((n_rigs, p * k)))
+        with jax.named_scope("temporal_match"):
+            pp, cp, w = pose_solver.temporal_correspondences(
+                prev, curr, self.pipe.orb, rx, ry, impl)
+        with jax.named_scope("pose_solve"):
+            pose = pose_solver.solve_pose_batched(
+                pp.reshape((n_rigs, p * k, 3)),
+                cp.reshape((n_rigs, p * k, 3)),
+                w.reshape((n_rigs, p * k)))
         return pts_flat, pose
 
     def _localize_frame(self, out: StereoOutput, prev: LocalizationState,
@@ -671,34 +708,41 @@ class VisualSystem:
         the rig degrades to its surviving stereo pairs — still 3
         launches, bit-exact on the surviving cameras.
         """
-        self._check_images(images, fleet=False, sequence=False)
-        camera_mask = self._coerce_camera_mask(camera_mask, None,
-                                               "process_frame")
-        if timestamps is not None:
-            dropped, camera_mask = self._frame_desync_mask(timestamps,
-                                                           camera_mask)
-            if dropped:
-                return None
-        if camera_mask is None:
-            out = self._jit(
-                "process_frame",
-                lambda im: self._frame_core(im, self.impl))(images)
-        else:
-            out = self._jit(
-                "process_frame_masked",
-                lambda im, cm: self._frame_core(im, self.impl, cm))(
-                    images, jnp.asarray(camera_mask))
-        if not self.pipe.localize:
-            return out
-        prev_state = self._resolve_prev(prev, "frame", out,
-                                        "process_frame")
-        pts, pose = self._jit(
-            "localize_frame",
-            lambda o, pv: self._localize_frame(o, pv, self.impl))(
-                out, prev_state)
-        lout = LocalizationOutput(out, pts, pose)
-        self._loc_state["frame"] = localization.state_from(lout)
-        return lout
+        self.counters["calls.process_frame"] += 1
+        with self._span("process_frame",
+                        call=self.counters["calls.process_frame"]):
+            with self._span("validate"):
+                self._check_images(images, fleet=False, sequence=False)
+                camera_mask = self._coerce_camera_mask(camera_mask, None,
+                                                       "process_frame")
+                if timestamps is not None:
+                    dropped, camera_mask = self._frame_desync_mask(
+                        timestamps, camera_mask)
+                    if dropped:
+                        return None
+            h2d = images.nbytes if isinstance(images, np.ndarray) else 0
+            with self._span("frame_call", h2d_bytes=h2d):
+                if camera_mask is None:
+                    out = self._jit(
+                        "process_frame",
+                        lambda im: self._frame_core(im, self.impl))(images)
+                else:
+                    out = self._jit(
+                        "process_frame_masked",
+                        lambda im, cm: self._frame_core(im, self.impl, cm))(
+                            images, jnp.asarray(camera_mask))
+            if not self.pipe.localize:
+                return out
+            with self._span("localize_call"):
+                prev_state = self._resolve_prev(prev, "frame", out,
+                                                "process_frame")
+                pts, pose = self._jit(
+                    "localize_frame",
+                    lambda o, pv: self._localize_frame(o, pv, self.impl))(
+                        out, prev_state)
+                lout = LocalizationOutput(out, pts, pose)
+                self._loc_state["frame"] = localization.state_from(lout)
+            return lout
 
     def process_fleet(self, images, timestamps=None, camera_mask=None,
                       prev: LocalizationState | None = None
@@ -850,19 +894,14 @@ class VisualSystem:
         ctx = sharding.current_ctx()
         if axis is None or ctx is None or axis not in dict(ctx.mesh.shape):
             return None
+        # The key leads with the plain entry name, so trace_count(entry)
+        # observes sharded retraces too.
         key = (entry, "sharded", axis, ctx.mesh)
         if key not in self._jitted:
             rig_dim = 1 if entry == "run_fleet" else 0
-            fn = sharding.shard_over(
+            self._jit(key, sharding.shard_over(
                 lambda x: core(x, self.impl), ctx.mesh, axis,
-                arg_axis=rig_dim)
-            def counted(x):
-                # count under the plain entry name so trace_count(entry)
-                # observes sharded retraces too
-                self._trace_counts[entry] = \
-                    self._trace_counts.get(entry, 0) + 1
-                return fn(x)
-            self._jitted[key] = jax.jit(counted)
+                arg_axis=rig_dim))
         return self._jitted[key]
 
     # -- feature / matcher entry points ------------------------------------

@@ -60,6 +60,7 @@ def level_shapes(cfg: ORBConfig) -> list[tuple[int, int]]:
     return [cfg.level_shape(lvl) for lvl in range(cfg.n_levels)]
 
 
+@jax.named_scope("pyramid")
 def build_pyramid_batched(images: jnp.ndarray, cfg: ORBConfig, *,
                           precision: str = "f32") -> list[jnp.ndarray]:
     """Batched pyramid: (B, H, W) -> list of (B, h_l, w_l) level images

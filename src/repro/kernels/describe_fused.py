@@ -304,6 +304,7 @@ def describe_fused_pyramid_pallas(raw_slabs: jnp.ndarray,
             jax.ShapeDtypeStruct((b, k, 16), jnp.uint32),
         ],
         interpret=interpret,
+        name="describe_fused_pyramid_pallas",
     )(xy.astype(jnp.int32).reshape(-1), hw.astype(jnp.int32).reshape(-1),
       jnp.asarray(steer_planes()), jnp.asarray(pack_weights()),
       _cast_slab(raw_slabs), _cast_slab(sm_slabs))

@@ -73,4 +73,5 @@ def fast_score_map_pallas(padded: jnp.ndarray, *, threshold: float,
         out_specs=pl.BlockSpec((TILE_H, TILE_W), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         interpret=interpret,
+        name="fast_score_map_pallas",
     )(padded.astype(jnp.float32))

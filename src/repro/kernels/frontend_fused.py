@@ -299,6 +299,7 @@ def frontend_fused_pallas(padded: jnp.ndarray, *, threshold: float,
             jax.ShapeDtypeStruct((b, h, w), out_dtypes[1]),
         ],
         interpret=interpret,
+        name="frontend_fused_pallas",
     )(padded)
 
 
@@ -346,4 +347,5 @@ def frontend_fused_pyramid_pallas(padded: jnp.ndarray, hw: jnp.ndarray, *,
             jax.ShapeDtypeStruct((n, h, w), out_dtypes[1]),
         ],
         interpret=interpret,
+        name="frontend_fused_pyramid_pallas",
     )(hw.astype(jnp.int32).reshape(-1), padded)

@@ -66,4 +66,5 @@ def gaussian_blur7_pallas(padded: jnp.ndarray, *, quantized: bool = True,
         out_specs=pl.BlockSpec((TILE_H, TILE_W), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         interpret=interpret,
+        name="gaussian_blur7_pallas",
     )(padded.astype(jnp.float32))

@@ -120,4 +120,5 @@ def hamming_match_pallas(desc_l: jnp.ndarray, meta_l: jnp.ndarray,
             jax.ShapeDtypeStruct((k,), jnp.int32),
         ],
         interpret=interpret,
+        name="hamming_match_pallas",
     )(desc_l, meta_l, desc_r, meta_r)

@@ -273,6 +273,7 @@ def match_rectify_fused_pallas(desc_l, meta_l, desc_r_t, meta_r_t, xy0,
             _column(n_pairs, k, jnp.int32),
         ],
         interpret=interpret,
+        name="match_rectify_fused_pallas",
     )(desc_l, meta_l, desc_r_t, meta_r_t, xy0.astype(jnp.float32),
       _cast_slab(img_l_padded), _cast_slab(img_r_padded))
 
@@ -307,6 +308,7 @@ def match_fused_pallas(desc_l, meta_l, desc_r_t, meta_r_t, *,
         out_shape=[_column(n_pairs, k, jnp.int32),
                    _column(n_pairs, k, jnp.int32)],
         interpret=interpret,
+        name="match_fused_pallas",
     )(desc_l, meta_l, desc_r_t, meta_r_t)
 
 
@@ -341,5 +343,6 @@ def sad_fused_pallas(xy_l, xy_r, img_l_padded, img_r_padded, *,
         out_specs=pl.BlockSpec((None, FM_BK, sweep), lambda p, i: (p, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pairs, k, sweep), jnp.int32),
         interpret=interpret,
+        name="sad_fused_pallas",
     )(xy_l.astype(jnp.float32), xy_r.astype(jnp.float32),
       _cast_slab(img_l_padded), _cast_slab(img_r_padded))

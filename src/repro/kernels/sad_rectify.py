@@ -52,4 +52,5 @@ def sad_search_pallas(left_patches: jnp.ndarray, right_strips: jnp.ndarray,
         out_specs=pl.BlockSpec((BK, sweep), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((k, sweep), jnp.int32),
         interpret=interpret,
+        name="sad_search_pallas",
     )(left_patches.astype(jnp.int32), right_strips.astype(jnp.int32))

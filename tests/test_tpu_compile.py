@@ -14,6 +14,7 @@ and only the one running this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +47,16 @@ def _compile(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernels(compiled):
+    """The instruction names of the Mosaic kernels, without their
+    ``.<n>`` suffix: the names a profiler trace shows, which the chip
+    benchmark's ``kernels/<k>.py`` ``NAMES`` match."""
+    text = compiled.as_text()
+    return [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_][\w-]*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)]
+
+
 def _levels():
     return [CFG.level_shape(lvl) for lvl in range(CFG.n_levels)]
 
@@ -63,7 +74,7 @@ def test_dense_fe_pyramid_compiles(one_chip, precision):
             x, hw, threshold=float(CFG.fast_threshold)),
         ((n, hc + halo, wc + halo), DTYPES[precision]),
         ((n, 2), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == ["frontend_fused_pyramid_pallas"]
 
 
 @pytest.mark.parametrize("precision", sorted(DTYPES))
@@ -81,7 +92,7 @@ def test_sparse_describe_pyramid_compiles(one_chip, precision):
         ((n, hc, wc), DTYPES[precision]), ((n, hc, wc), DTYPES[precision]),
         ((CAMERAS, sum(blocks) * kb, 2), jnp.int32),
         ((sum(blocks), 2), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == ["describe_fused_pyramid_pallas"]
 
 
 def _fm_slab(ry, rx):
@@ -121,7 +132,7 @@ def test_fm_megakernel_compiles_with_resident_slabs(one_chip, precision,
     # Arguments = the slabs of both pairs + descriptor/meta rows (< 1 MiB).
     extra = mem.argument_size_in_bytes - PAIRS * slab_bytes
     assert 0 <= extra < 2 ** 20, mem
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == ["match_rectify_fused_pallas"]
 
 
 @pytest.mark.parametrize("n_pairs", [PAIRS, 4 * PAIRS])
@@ -135,4 +146,4 @@ def test_match_only_kernel_compiles(one_chip, n_pairs):
             dl, ml, dr, mr, row_band=48.0, max_disparity=48.0),
         ((n_pairs, k, 8), jnp.uint32), ((n_pairs, k, 4), jnp.float32),
         ((n_pairs, 8, k), jnp.uint32), ((n_pairs, 4, k), jnp.float32))
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == ["match_fused_pallas"]
