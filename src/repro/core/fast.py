@@ -26,35 +26,89 @@ from repro.kernels.ref import nms3  # noqa: F401  (oracle; back-compat export)
 from repro.kernels.ref import PATCH, RADIUS  # noqa: F401
 
 
+def _order_key(flat: jnp.ndarray) -> jnp.ndarray:
+    """An integer key with the order and the equalities of ``flat``.
+    Integers are their own key.  A float maps to the signed integer of
+    its bits, the bits below the sign flipped for negatives, after
+    ``-0.0`` becomes ``+0.0`` so that equal floats have equal keys."""
+    if jnp.issubdtype(flat.dtype, jnp.integer):
+        return flat
+    itype = jnp.dtype(f"int{8 * flat.dtype.itemsize}")
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(flat == 0, jnp.zeros_like(flat), flat), itype)
+    return jnp.where(bits < 0, bits ^ jnp.iinfo(itype).max, bits)
+
+
+def _kth_largest(key: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The k-th largest of ``key``: the largest t with
+    ``sum(key >= t) >= k``, bisected between the smallest and the
+    largest key, so the trip count follows the data's range (about 9
+    for FAST scores in [0, 255])."""
+    def step(bounds):
+        lo, hi = bounds
+        # ceil((lo + hi) / 2) without forming lo + hi, which can overflow.
+        mid = (lo >> 1) + (hi >> 1) + ((lo | hi) & 1)
+        ok = jnp.sum((key >= mid).astype(jnp.int32)) >= k
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
+
+    lo, _ = jax.lax.while_loop(lambda b: b[0] < b[1], step,
+                               (jnp.min(key), jnp.max(key)))
+    return lo
+
+
+def _nth_true(mask: jnp.ndarray, n: jnp.ndarray) -> jnp.ndarray:
+    """Flat position of the n-th (from 1) True of the 1-D ``mask`` for
+    each entry of ``n``, which must not exceed the count of True.
+
+    A search in two levels, so that no running count of the whole mask
+    is formed: the mask is cut into rows of 128, a binary search of the
+    running count of the row totals finds each n-th True's row, and a
+    running count inside that row alone finds its column."""
+    width = 128
+    rows = -(-mask.shape[0] // width)
+    grid = jnp.pad(mask, (0, rows * width - mask.shape[0])).reshape(
+        rows, width)
+    upto = jnp.cumsum(jnp.sum(grid, axis=1, dtype=jnp.int32))
+    row = jnp.searchsorted(upto, n, method="scan")
+    before = jnp.where(row > 0, upto[row - 1], 0)
+    in_row = jnp.cumsum(grid[row].astype(jnp.int32), axis=-1)
+    col = jnp.argmax(in_row >= (n - before)[..., None], axis=-1)
+    return row * width + col.astype(row.dtype)
+
+
 @jax.named_scope("select_topk")
 def select_topk(score: jnp.ndarray, k: int, border: int):
     """Top-K corners of a score map. Returns (xy (K,2) int32, score (K,),
     valid (K,) bool), ordered by score descending, ties by the lower
     flat index first.
 
-    ``lax.top_k`` promises that tie order, but the TPU lowers it to
-    chunked sorts whose comparator sees values only, so equal scores
-    (FAST scores are small integers: ties are common) came back in an
-    order that changed with the batch size.  Here top-K only supplies
-    the K-th value; which of the tied corners at that value are kept
-    (the lowest indices), and the order of the K, are decided
-    explicitly.  Its device ops carry the ``select_topk`` scope."""
+    No ``lax.top_k``: the TPU lowers it to a full sort of the map (four
+    of them took three quarters of a 720p quad frame), and its chunked
+    sorts see values only, so the order of equal scores (FAST scores
+    are small integers: ties are common) changed with the batch size.
+    Instead the K-th score is bisected by counting, the lowest-index
+    corners tied at it are kept, the K kept corners are listed in index
+    order by searching the running count of kept corners, and only
+    those K are sorted.  Its device ops carry the ``select_topk``
+    scope."""
     h, w = score.shape
+    if not 0 < k <= h * w:
+        raise ValueError(f"k={k} outside 1..{h * w}, the size of the map")
     row = jnp.arange(h)[:, None]
     col = jnp.arange(w)[None, :]
     inside = ((row >= border) & (row < h - border)
               & (col >= border) & (col < w - border))
     flat = jnp.where(inside, score, jnp.zeros_like(score)).reshape(-1)
-    kth = jax.lax.top_k(flat, k)[0][k - 1]
-    above = flat > kth
-    at = flat == kth
+    key = _order_key(flat)
+    kth = _kth_largest(key, k)
+    above = key > kth
+    at = key == kth
+    # Of the corners tied at the K-th score, the n_at lowest-index ones
+    # are kept: those up to the n_at-th.
     n_at = k - jnp.sum(above.astype(jnp.int32))
-    keep = above | (at & (jnp.cumsum(at.astype(jnp.int32)) <= n_at))
-    # Exactly k corners are kept; their negated flat indices are unique
-    # keys, so this top-K is tie-free and returns them by index.
     pos = jnp.arange(flat.shape[0], dtype=jnp.int32)
-    idx = -jax.lax.top_k(jnp.where(keep, -pos, jnp.iinfo(jnp.int32).min),
-                         k)[0]
+    keep = above | (at & (pos <= _nth_true(at, n_at)))
+    idx = _nth_true(keep, jnp.arange(1, k + 1, dtype=jnp.int32))
     neg_vals, idx = jax.lax.sort((-flat[idx], idx), num_keys=2)
     vals = -neg_vals
     ys = idx // w

@@ -64,19 +64,56 @@ def test_topk_respects_border_and_static_shape():
     assert np.all(ys[v] >= 16) and np.all(ys[v] < 48)
 
 
-@pytest.mark.parametrize("batch", [1, 4, 16])
-def test_topk_tie_order_is_score_then_lowest_index(batch):
+def _topk_scores(case, dtype, h, w, border):
+    """(scores (B, H, W), K) of one tie-order case; ``dtype`` picks how
+    a case's values are drawn where the two dtypes differ."""
+    is_float = np.dtype(dtype).kind == "f"
+    if case.startswith("batch"):
+        batch = int(case[len("batch"):])
+        rng = np.random.RandomState(batch)
+        return rng.randint(0, 4, (batch, h, w)), 150
+    rng = np.random.RandomState(7)
+    shape = (4, h, w)
+    if case == "fewer_positive_than_k":
+        scores = np.zeros(shape)
+        scores.reshape(4, -1)[:, rng.choice(h * w, 40, replace=False)] = \
+            rng.randint(1, 256, (4, 40))
+        return scores, 150
+    if case == "all_zero":
+        return np.zeros(shape), 150
+    if case == "k_every_inside_pixel":
+        return rng.randint(0, 4, shape), (h - 2 * border) * (w - 2 * border)
+    if case == "signed_zeros":
+        # -0.0 beside +0.0 (the same 0 in int16), below and at the K-th.
+        return rng.choice([-0.0, 0.0, 1.0], shape, p=[.4, .4, .2]), 1000
+    if case == "full_depth":
+        # non-integer floats over ~2^20 (the whole int16 range for
+        # int16): the bisection runs to its full depth.
+        if is_float:
+            return rng.uniform(0, 2.0 ** 20, shape), 150
+        return rng.randint(-2 ** 15, 2 ** 15, shape), 150
+    if case == "only_0_and_255":
+        return rng.choice([0, 255], shape), 150
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "batch1", "batch4", "batch16", "fewer_positive_than_k", "all_zero",
+    "k_every_inside_pixel", "signed_zeros", "full_depth", "only_0_and_255"])
+@pytest.mark.parametrize("dtype", [np.int16, np.float32])
+def test_topk_tie_order_is_score_then_lowest_index(dtype, case):
     """FAST scores are small integers, so ties are the rule: the kept
     corners and their order must be (score descending, flat index
-    ascending) for every camera whatever the batch size — the contract
-    the TPU's chunked top-K lowering does not keep by itself."""
-    rng = np.random.RandomState(batch)
-    h, w, k, border = 40, 56, 150, 3
-    scores = rng.randint(0, 4, (batch, h, w)).astype(np.float32)
+    ascending) for every camera whatever the batch size, the dtype or
+    the range of the scores, with the lowest-index zeros filling K when
+    fewer corners score above 0."""
+    h, w, border = 40, 56, 3
+    raw, k = _topk_scores(case, dtype, h, w, border)
+    scores = raw.astype(dtype)
     xy, vals, valid = jax.vmap(
         lambda s: fast.select_topk(s, k, border))(jnp.asarray(scores))
-    for b in range(batch):
-        masked = np.zeros((h, w), np.float32)
+    for b in range(scores.shape[0]):
+        masked = np.zeros((h, w), dtype)
         masked[border:h - border, border:w - border] = \
             scores[b, border:h - border, border:w - border]
         flat = masked.reshape(-1)
@@ -85,6 +122,31 @@ def test_topk_tie_order_is_score_then_lowest_index(batch):
                                       np.stack([want % w, want // w], -1))
         np.testing.assert_array_equal(np.asarray(vals[b]), flat[want])
         np.testing.assert_array_equal(np.asarray(valid[b]), flat[want] > 0)
+
+
+def test_topk_lowers_without_a_full_map_sort():
+    """The TPU lowers ``top_k`` and ``sort`` to full sorts: at 720p no
+    such op may take a whole score map (4 x 921,600) as an operand —
+    only the K survivors are sorted.  A lowering, nothing runs."""
+    lowered = jax.jit(jax.vmap(lambda s: fast.select_topk(s, 545, 16))).lower(
+        jax.ShapeDtypeStruct((4, 720, 1280), jnp.int16))
+    sorts = []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    inner = inner.operation
+                    if "sort" in inner.name or "top_k" in inner.name:
+                        sorts.append((inner.name,
+                                      [str(v.type) for v in inner.operands]))
+                    walk(inner)
+
+    walk(lowered.compiler_ir("stablehlo").operation)
+    assert sorts, "the K survivors' sort is missing"
+    for name, operands in sorts:
+        assert not any(str(720 * 1280) in t for t in operands), (name,
+                                                                 operands)
 
 
 def test_orientation_points_toward_bright_side():
