@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import matching, orb
+from repro.core import matching, orb, pyramid
 from repro.core import sync as sync_mod
 from repro.core.rig import DesyncError, RigConfig
 from repro.core.types import (CameraIntrinsics, FeatureSet,
@@ -180,9 +180,10 @@ class VisualSystem:
     ``process_frame`` records profiler spans: ``repro.process_frame``
     (with ``call``, the call number) around ``repro.validate``,
     ``repro.frame_call`` (with ``h2d_bytes``: the bytes of a host NumPy
-    frame handed to the program, 0 for a device array) and
-    ``repro.localize_call``; with no profiler running a span costs one
-    check.
+    frame handed to the program, 0 for a device array, and the frame's
+    kernel grids from static shapes, ``ops.frame_grids``: ``fe_tiles``,
+    ``fe_tiles_real``, ``fm_pairs``) and ``repro.localize_call``; with
+    no profiler running a span costs one check.
     """
 
     def __init__(self, rig: RigConfig,
@@ -201,6 +202,10 @@ class VisualSystem:
         self.impl: str = ops.resolve_impl(self.pipe.impl)
         self._jitted: dict = {}
         self.counters: collections.Counter = collections.Counter()
+        cfg = self.pipe.orb
+        self._frame_grids = ops.frame_grids(
+            pyramid.level_shapes(cfg), rig.n_cameras, rig.n_pairs,
+            sum(cfg.features_per_level()))
         # Bounded health log: one spread per checked frame; a streaming
         # session at 30 fps would otherwise grow this without limit.
         self.desync_log: "collections.deque[float]" = collections.deque(
@@ -721,7 +726,8 @@ class VisualSystem:
                     if dropped:
                         return None
             h2d = images.nbytes if isinstance(images, np.ndarray) else 0
-            with self._span("frame_call", h2d_bytes=h2d):
+            with self._span("frame_call", h2d_bytes=h2d,
+                            **self._frame_grids):
                 if camera_mask is None:
                     out = self._jit(
                         "process_frame",

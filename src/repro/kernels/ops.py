@@ -184,6 +184,43 @@ def _pad_tiles(img: jnp.ndarray, halo: int, th: int, tw: int):
     return padded, (h, w)
 
 
+def _round_up(n: int, mult: int) -> int:
+    return n + (-n) % mult
+
+
+def dense_fe_canvas(shapes) -> tuple[int, int]:
+    """(Hc, Wc) of the whole-pyramid dense kernel's common canvas: the
+    largest level rounded up to whole TILE_H x TILE_W tiles.  Every
+    camera x level slab is padded to it."""
+    return (max(_round_up(h, TILE_H) for h, _ in shapes),
+            max(_round_up(w, TILE_W) for _, w in shapes))
+
+
+def fm_rows(k: int, m: int, match_only: bool = False) -> tuple[int, int]:
+    """(K, M) rows of a pair in the FM grid: the K left features padded
+    to the left block, the M right features to FM_BM."""
+    return _round_up(k, MO_BK if match_only else FM_BK), _round_up(m, FM_BM)
+
+
+def frame_grids(level_shapes, n_cameras: int, n_pairs: int,
+                k: int) -> dict:
+    """What one rig frame's dense FE and stereo FM kernels walk, from
+    static shapes alone and by the functions their wrappers pad with:
+    ``fe_tiles``, the dense grid's tiles over every camera x level slab;
+    ``fe_tiles_real``, those that hold level pixels (the rest is canvas
+    padding); ``fm_pairs``, the left x right candidate slots of the FM
+    grid over every pair (K features a side)."""
+    hc, wc = dense_fe_canvas(level_shapes)
+    real = sum(_round_up(h, TILE_H) // TILE_H * (_round_up(w, TILE_W)
+                                                 // TILE_W)
+               for h, w in level_shapes)
+    kp, mp = fm_rows(k, k)
+    return {"fe_tiles": (n_cameras * len(level_shapes)
+                         * (hc // TILE_H) * (wc // TILE_W)),
+            "fe_tiles_real": n_cameras * real,
+            "fm_pairs": n_pairs * kp * mp}
+
+
 def fast_score_map(img: jnp.ndarray, threshold: float,
                    impl: str | None = None) -> jnp.ndarray:
     """(H, W) image -> (H, W) float32 FAST-9/16 corner score map."""
@@ -379,13 +416,13 @@ def fast_blur_nms_pyramid(levels, threshold: float, *, nms: bool = True,
                 for lv in levels]
     shapes = [(int(lv.shape[1]), int(lv.shape[2])) for lv in levels]
     b = levels[0].shape[0]
-    hc = max(h + (-h) % TILE_H for h, _ in shapes)
-    wc = max(w + (-w) % TILE_W for _, w in shapes)
-    flat = jnp.concatenate([
-        jnp.pad(_cast_slab(lv),
-                ((0, 0), (FUSED_HALO, FUSED_HALO + hc - h),
-                 (FUSED_HALO, FUSED_HALO + wc - w)), mode="edge")
-        for lv, (h, w) in zip(levels, shapes)], axis=0)
+    hc, wc = dense_fe_canvas(shapes)
+    with jax.named_scope("canvas"):
+        flat = jnp.concatenate([
+            jnp.pad(_cast_slab(lv),
+                    ((0, 0), (FUSED_HALO, FUSED_HALO + hc - h),
+                     (FUSED_HALO, FUSED_HALO + wc - w)), mode="edge")
+            for lv, (h, w) in zip(levels, shapes)], axis=0)
     hw = jnp.asarray(np.repeat(np.asarray(shapes, np.int32), b, axis=0))
     _count_launches()
     blur, score = frontend_fused_pyramid_pallas(
@@ -492,10 +529,12 @@ def orient_describe_pyramid(raws, smootheds, xys, *,
                        ((0, 0), (rad, hc - h - rad), (rad, wc - w - rad)),
                        mode="edge")
 
-    raw_all = jnp.concatenate(
-        [slab(im, h, w) for im, (h, w) in zip(raws, shapes)], axis=0)
-    sm_all = jnp.concatenate(
-        [slab(im, h, w) for im, (h, w) in zip(smootheds, shapes)], axis=0)
+    with jax.named_scope("canvas"):
+        raw_all = jnp.concatenate(
+            [slab(im, h, w) for im, (h, w) in zip(raws, shapes)], axis=0)
+        sm_all = jnp.concatenate(
+            [slab(im, h, w) for im, (h, w) in zip(smootheds, shapes)],
+            axis=0)
     ks = [int(xy.shape[1]) for xy in xys]
     kps = [(-k) % KP_BLOCK for k in ks]
     xy_all = jnp.concatenate(
@@ -582,10 +621,10 @@ def sad_search(left_patches: jnp.ndarray, right_strips: jnp.ndarray,
     return sad_search_pallas(lp, rs, interpret=_interpret())[:k]
 
 
-def _pad_axis1(x: jnp.ndarray, mult: int):
-    """Zero-pad axis 1 (the K/M feature axis of pair-batched arrays) up
-    to a multiple of ``mult``; padded meta rows carry valid=0."""
-    p = (-x.shape[1]) % mult
+def _pad_axis1(x: jnp.ndarray, rows: int):
+    """Zero-pad axis 1 (the K/M feature axis of pair-batched arrays) out
+    to ``rows``; padded meta rows carry valid=0."""
+    p = rows - x.shape[1]
     if p == 0:
         return x
     pad_width = [(0, 0), (0, p)] + [(0, 0)] * (x.ndim - 2)
@@ -616,11 +655,11 @@ def _check_sad_window(sad_window: int, sad_range: int) -> None:
             f"the kernel's aligned ({SAD_WIN_H}, {SAD_WIN_W}) window")
 
 
-def _right_t(desc_r: jnp.ndarray, meta_r: jnp.ndarray):
-    """Pad the right side to FM_BM rows and transpose it to the
-    kernels' lane-dense (P, 8, M) / (P, 4, M) layout."""
-    return (jnp.swapaxes(_pad_axis1(desc_r, FM_BM), 1, 2),
-            jnp.swapaxes(_pad_axis1(meta_r, FM_BM), 1, 2))
+def _right_t(desc_r: jnp.ndarray, meta_r: jnp.ndarray, rows: int):
+    """Pad the right side to ``rows`` and transpose it to the kernels'
+    lane-dense (P, 8, M) / (P, 4, M) layout."""
+    return (jnp.swapaxes(_pad_axis1(desc_r, rows), 1, 2),
+            jnp.swapaxes(_pad_axis1(meta_r, rows), 1, 2))
 
 
 def _match_rectify_jnp(dl, ml, dr, mr, il, ir, row_band, max_disparity,
@@ -683,10 +722,10 @@ def match_rectify_fused(desc_l: jnp.ndarray, meta_l: jnp.ndarray,
                 a, b, c, d, e, f, row_band, max_disparity, max_hamming,
                 sad_window, sad_range)
         )(desc_l, meta_l, desc_r, meta_r, img_l, img_r)
-    bk = MO_BK if match_only else FM_BK
-    dl = _pad_axis1(desc_l, bk)
-    ml = _pad_axis1(meta_l, bk)
-    dr, mr = _right_t(desc_r, meta_r)
+    kp, mp = fm_rows(k, desc_r.shape[1], match_only)
+    dl = _pad_axis1(desc_l, kp)
+    ml = _pad_axis1(meta_l, kp)
+    dr, mr = _right_t(desc_r, meta_r, mp)
     _count_launches()
     if match_only:
         dist, idx = match_fused_pallas(
@@ -734,9 +773,10 @@ def sad_patch_search(img_l: jnp.ndarray, img_r: jnp.ndarray,
     _, h, w = img_l.shape
     ry = sad_window // 2
     _count_launches()
+    kp = _round_up(k, FM_BK)
     table = sad_fused_pallas(
-        _pad_axis1(xy_l.astype(jnp.float32), FM_BK),
-        _pad_axis1(xy_r.astype(jnp.float32), FM_BK),
+        _pad_axis1(xy_l.astype(jnp.float32), kp),
+        _pad_axis1(xy_r.astype(jnp.float32), kp),
         _pad_fm_slab(img_l, ry, ry),
         _pad_fm_slab(img_r, ry, ry + sad_range),
         patch=int(sad_window), sad_range=int(sad_range), true_h=h,

@@ -8,7 +8,9 @@ VMEM than a kernel may use.  These tests compile each kernel the frame
 path launches — the dense FE pyramid, the sparse describe pyramid, the
 FM megakernel and the match-only kernel of the temporal match — at the
 paper's 1280x720 quad rig with K = 1000 (``ORBConfig()``), in f32 and
-uint8, with ``interpret=False``.  The topology is described inside a
+uint8, with ``interpret=False``; and each frame-path stage through its
+``ops`` wrapper at ORB-SLAM2's KITTI stereo shapes (one 1241x376 pair,
+8 levels, K = 2,000) in uint8.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests
 and only the one running this file loads the TPU compiler.
 """
@@ -147,3 +149,52 @@ def test_match_only_kernel_compiles(one_chip, n_pairs):
         ((n_pairs, k, 8), jnp.uint32), ((n_pairs, k, 4), jnp.float32),
         ((n_pairs, 8, k), jnp.uint32), ((n_pairs, 4, k), jnp.float32))
     assert _kernels(compiled) == ["match_fused_pallas"]
+
+
+#: ORB-SLAM2's KITTI stereo settings: one 1241x376 pair, 8 levels at
+#: 1.2, K = 2,000 (``benchmarks/chip/configs/kitti_stereo.json``).
+KITTI = ORBConfig(height=376, width=1241, n_levels=8, max_features=2000,
+                  max_disparity=718, max_hamming=75)
+
+
+def _kitti_stage(stage):
+    """(program, argument shapes, its kernel) of one uint8 frame-path
+    stage at KITTI's shapes, through the ``ops`` wrappers that pad."""
+    shapes = [KITTI.level_shape(lvl) for lvl in range(KITTI.n_levels)]
+    ks = KITTI.features_per_level()
+    k, n = sum(ks), len(shapes)
+    slabs = [((2,) + s, jnp.uint8) for s in shapes]
+    rows = [((1, k, 8), jnp.uint32), ((1, k, 4), jnp.float32)] * 2
+    if stage == "dense_fe":
+        return (lambda *lv: ops.fast_blur_nms_pyramid(
+                    list(lv), float(KITTI.fast_threshold), impl="pallas"),
+                slabs, "frontend_fused_pyramid_pallas")
+    if stage == "describe":
+        return (lambda *a: ops.orient_describe_pyramid(
+                    list(a[:n]), list(a[n:2 * n]), list(a[2 * n:]),
+                    impl="pallas"),
+                slabs + slabs + [((2, kl, 2), jnp.int32) for kl in ks],
+                "describe_fused_pyramid_pallas")
+    if stage == "fm":
+        return (lambda dl, ml, dr, mr, il, ir: ops.match_rectify_fused(
+                    dl, ml, dr, mr, il, ir, row_band=float(KITTI.row_band),
+                    max_disparity=float(KITTI.max_disparity),
+                    max_hamming=KITTI.max_hamming, impl="pallas"),
+                rows + [((1, KITTI.height, KITTI.width), jnp.uint8)] * 2,
+                "match_rectify_fused_pallas")
+    return (lambda dl, ml, dr, mr: ops.match_rectify_fused(
+                dl, ml, dr, mr, row_band=48.0, max_disparity=96.0,
+                impl="pallas"),
+            rows, "match_fused_pallas")
+
+
+@pytest.mark.parametrize("stage", ["dense_fe", "describe", "fm",
+                                   "temporal_match"])
+def test_kitti_stereo_stages_compile(one_chip, monkeypatch, stage):
+    """The width of 1241 px, off the 128 lanes, 16 camera x level slabs
+    on one canvas, 8 level blocks of keypoints and the FM at K = 2,000,
+    uint8, through the wrappers' own padding (interpret mode, which the
+    CPU backend picks, is turned off here)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    fn, shapes, kernel = _kitti_stage(stage)
+    assert _kernels(_compile(one_chip, fn, *shapes)) == [kernel]

@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core import (CameraIntrinsics, ORBConfig, PipelineConfig,
-                        RigConfig, VisualSystem)
+                        RigConfig, VisualSystem, pyramid)
+from repro.kernels import ops
 
 H, W = 48, 64
 CHILDREN = ("repro.validate", "repro.frame_call", "repro.localize_call")
@@ -102,6 +103,35 @@ def test_counters_count_calls_bytes_and_traces(tmp_path):
     calls = [s for s in spans if s[0] == "repro.frame_call"]
     assert [int(s[4]["h2d_bytes"]) for s in calls] == [
         frame.nbytes, frame.nbytes, 0]
+
+
+@pytest.mark.parametrize("shape,rig,want", [
+    # ORB-SLAM2's KITTI stereo: 8 levels of 376x1241 on a 3 x 10 tile
+    # canvas, 2 cameras; one pair, 2,000 x 2,048 slots
+    ((376, 1241, 8, 2000), (2, 1), (480, 230, 4_096_000)),
+    # the paper's quad rig: 2 levels of 720x1280 on 6 x 10, 4 cameras;
+    # two pairs, 1,000 x 1,024 slots
+    ((720, 1280, 2, 1000), (4, 2), (480, 420, 2_048_000)),
+], ids=["kitti_stereo", "quad720"])
+def test_frame_grids_from_static_shapes(shape, rig, want):
+    h, w, levels, k = shape
+    cfg = ORBConfig(height=h, width=w, n_levels=levels, max_features=k)
+    got = ops.frame_grids(pyramid.level_shapes(cfg), *rig,
+                          sum(cfg.features_per_level()))
+    assert (got["fe_tiles"], got["fe_tiles_real"], got["fm_pairs"]) == want
+
+
+def test_frame_calls_carry_their_kernel_grids(tmp_path):
+    vs = _quad()
+    frame = _frame(4)
+    spans = _profiled(tmp_path, vs.process_frame, [frame, frame])
+    calls = [s for s in spans if s[0] == "repro.frame_call"]
+    # 48x64 and 40x53 both fit one 128x128 tile: 4 cameras x 2 levels;
+    # 2 pairs of 16 x 128 slots
+    want = {"fe_tiles": 8, "fe_tiles_real": 8, "fm_pairs": 2 * 16 * 128}
+    assert len(calls) == 2
+    for s in calls:
+        assert {k: int(s[4][k]) for k in want} == want
 
 
 def test_desync_drop_hands_nothing_over(tmp_path):
