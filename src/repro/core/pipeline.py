@@ -182,8 +182,9 @@ class VisualSystem:
     ``repro.frame_call`` (with ``h2d_bytes``: the bytes of a host NumPy
     frame handed to the program, 0 for a device array, and the frame's
     kernel grids from static shapes, ``ops.frame_grids``: ``fe_tiles``,
-    ``fe_tiles_real``, ``fm_pairs``) and ``repro.localize_call``; with
-    no profiler running a span costs one check.
+    ``fe_tiles_real``, ``fm_pairs``, and ``ops.describe_grid``:
+    ``describe_bf16_slots``) and ``repro.localize_call``; with no
+    profiler running a span costs one check.
     """
 
     def __init__(self, rig: RigConfig,
@@ -203,9 +204,13 @@ class VisualSystem:
         self._jitted: dict = {}
         self.counters: collections.Counter = collections.Counter()
         cfg = self.pipe.orb
-        self._frame_grids = ops.frame_grids(
-            pyramid.level_shapes(cfg), rig.n_cameras, rig.n_pairs,
-            sum(cfg.features_per_level()))
+        slab_dtype = (np.uint8 if self.pipe.precision == "uint8"
+                      else np.float32)
+        self._frame_grids = {
+            **ops.frame_grids(pyramid.level_shapes(cfg), rig.n_cameras,
+                              rig.n_pairs, sum(cfg.features_per_level())),
+            **ops.describe_grid(cfg.features_per_level(), rig.n_cameras,
+                                slab_dtype)}
         # Bounded health log: one spread per checked frame; a streaming
         # session at 30 fps would otherwise grow this without limit.
         self.desync_log: "collections.deque[float]" = collections.deque(

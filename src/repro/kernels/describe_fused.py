@@ -31,7 +31,19 @@ Steering is LUT-binned as in the paper: theta is quantized to 12 bins of
 ROM.  Taps are resolved GATHER-FREE with one-hot matmuls on the MXU
 (column select, then a masked row sum), so ``tau = p(A) < p(B)`` becomes
 the sign of an exactly computed difference — BIT-exact against the
-gather oracle (``ref.lut_descriptor``).
+gather oracle (``ref.lut_descriptor``).  How many MXU passes that takes
+follows the slab dtype:
+
+  * integer slabs (the uint8 datapath) hold integers 0-255, exact in
+    bf16's 8-bit significand (every integer up to 256 is), and the
+    one-hot's 0/1 are exact too; each tap output has ONE nonzero product
+    (a pixel times 1), the rest are zeros, so a single bf16 pass with
+    f32 accumulation returns the pixel exactly;
+  * float slabs hold arbitrary f32 values, which bf16 would round: their
+    taps stay f32 ``Precision.HIGHEST`` (six bf16 passes, exact);
+  * the bit pack multiplies 0/1 bits by distinct powers of two below
+    2^16 — exact in bf16 — and their sum is exact in f32 in any order,
+    so it takes one bf16 pass for every slab dtype.
 
 Mosaic has no ``atan2``.  The kernel therefore does not decide the bin:
 it computes the moments exactly (int32 for uint8 slabs; f32 sums of
@@ -83,15 +95,23 @@ def steer_planes() -> np.ndarray:
 
 
 def pack_weights() -> np.ndarray:
-    """(256, 16) f32: bits @ W gives the low (cols 0-7) and high (cols
-    8-15) 16-bit halves of the 8 descriptor words — sums of distinct
-    powers of two below 2^16, exact in f32."""
+    """(256, 16) bf16: bits @ W gives the low (cols 0-7) and high (cols
+    8-15) 16-bit halves of the 8 descriptor words — each weight a power
+    of two below 2^16 (exact in bf16), each half a sum of distinct ones
+    (exact in f32)."""
     p = np.arange(_N_PAIRS)
     w = np.zeros((_N_PAIRS, 16), np.float32)
     lo = p % 32 < 16
     w[p[lo], p[lo] // 32] = 2.0 ** (p[lo] % 32)
     w[p[~lo], 8 + p[~lo] // 32] = 2.0 ** (p[~lo] % 32 - 16)
-    return w
+    return w.astype(jnp.bfloat16)
+
+
+def single_pass_taps(dtype) -> bool:
+    """Whether the kernel resolves the taps of slabs of ``dtype`` in one
+    bf16 MXU pass: integer slabs (0-255) are exact in bf16, float slabs
+    are not and keep f32 ``Precision.HIGHEST``."""
+    return bool(jnp.issubdtype(dtype, jnp.integer))
 
 
 def aligned_window(img_ref, y, x, rows: int, cols: int, win_h: int,
@@ -152,33 +172,47 @@ def candidate_bins(m10, m01):
     return lo % _N_BINS, hi % _N_BINS
 
 
+def _tap_patch(sm):
+    """The smoothed patch as the taps' MXU operand: an integer patch
+    (0-255) as bf16, exact; a float patch as it is, f32."""
+    if single_pass_taps(sm.dtype):
+        return sm.astype(jnp.bfloat16)
+    return sm
+
+
 def _steered_bits(lut_ref, sm, b):
-    """(31, 31) f32 smoothed patch + (1, 1) bin -> (1, 256) f32 tau
-    bits.  Each tap is an exact one-hot matmul (column select) then a
-    one-hot masked row sum, so p(B) - p(A) is the correctly rounded
-    difference of the two pixel values and its sign is exact."""
+    """(31, 31) smoothed patch from ``_tap_patch`` + (1, 1) bin ->
+    (1, 256) bf16 tau bits.  Each tap is a one-hot matmul (column
+    select) then a one-hot masked row sum.  A bf16 patch holds integers
+    up to 255 (exact in bf16) and each tap output has one nonzero
+    product, the pixel times 1, so one bf16 pass accumulated in f32
+    returns the pixel exactly; an f32 patch takes ``Precision.HIGHEST``,
+    exact too.  So p(B) - p(A) is the correctly rounded difference of
+    the two pixel values and its sign is exact."""
     binoh = (jax.lax.broadcasted_iota(jnp.int32, (_N_BINS, 1), 0)
              == b).astype(jnp.int32)
     ya, xa, yb, xb = (jnp.sum(lut_ref[q] * binoh, axis=0, keepdims=True)
                       for q in range(4))                   # (1, 256) each
     pos = jax.lax.broadcasted_iota(jnp.int32, (PATCH, _N_PAIRS), 0)
+    precision = (None if sm.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
 
     def tap(yq, xq):
-        cols = jnp.dot(sm, (pos == xq).astype(jnp.float32),
+        cols = jnp.dot(sm, (pos == xq).astype(sm.dtype),
                        preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)  # (31, 256)
+                       precision=precision)                # (31, 256)
         return jnp.sum(jnp.where(pos == yq, cols, 0.0), axis=0,
                        keepdims=True)
 
-    return (tap(yb, xb) - tap(ya, xa) > 0.0).astype(jnp.float32)
+    return (tap(yb, xb) - tap(ya, xa) > 0.0).astype(jnp.bfloat16)
 
 
 def _pack(bits, pack_ref):
-    """(1, 256) f32 bits -> (1, 8) uint32 words (bit i of word i // 32,
-    ``ref.pack_bits``'s layout)."""
+    """(1, 256) bf16 bits -> (1, 8) uint32 words (bit i of word i // 32,
+    ``ref.pack_bits``'s layout), in one bf16 pass: 0/1 times distinct
+    powers of two below 2^16, summed exactly in f32."""
     halves = jnp.dot(bits, pack_ref[...],
-                     preferred_element_type=jnp.float32,
-                     precision=jax.lax.Precision.HIGHEST)   # (1, 16)
+                     preferred_element_type=jnp.float32)   # (1, 16)
     halves = halves.astype(jnp.int32).astype(jnp.uint32)
     return halves[:, :8] | (halves[:, 8:] << 16)
 
@@ -201,8 +235,8 @@ def _describe_kernel(xy_ref, hw_ref, lut_ref, pack_ref, raw_ref, sm_ref,
         x = jnp.clip(xy_ref[base], 0, true_w - 1)
         y = jnp.clip(xy_ref[base + 1], 0, true_h - 1)
         raw = aligned_window(raw_ref, y, x, PATCH, PATCH, WIN_H, WIN_W)
-        sm = aligned_window(sm_ref, y, x, PATCH, PATCH, WIN_H, WIN_W)
-        sm = sm.astype(jnp.float32)
+        sm = _tap_patch(
+            aligned_window(sm_ref, y, x, PATCH, PATCH, WIN_H, WIN_W))
         m10, m01 = _moments(raw)
         b_lo, b_hi = candidate_bins(m10, m01)
         words = jnp.concatenate(

@@ -35,7 +35,8 @@ import numpy as np
 from repro.kernels import ref as _ref
 from repro.kernels.describe_fused import (KP_BLOCK, WIN_H, WIN_W,
                                           _cast_slab,
-                                          describe_fused_pyramid_pallas)
+                                          describe_fused_pyramid_pallas,
+                                          single_pass_taps)
 from repro.kernels.fast_detect import (HALO, TILE_H, TILE_W,
                                        fast_score_map_pallas)
 from repro.kernels.frontend_fused import (FUSED_HALO, fast_score_from_taps,
@@ -219,6 +220,17 @@ def frame_grids(level_shapes, n_cameras: int, n_pairs: int,
                          * (hc // TILE_H) * (wc // TILE_W)),
             "fe_tiles_real": n_cameras * real,
             "fm_pairs": n_pairs * kp * mp}
+
+
+def describe_grid(level_ks, n_cameras: int, slab_dtype) -> dict:
+    """What one rig frame's sparse describe kernel resolves in one bf16
+    MXU pass, from static shapes: ``describe_bf16_slots``, the keypoint
+    slots of its grid (every camera's level K padded to KP_BLOCK, as
+    ``orient_describe_pyramid`` pads) on integer slabs; 0 on float
+    slabs, whose taps keep f32 ``Precision.HIGHEST``."""
+    slots = n_cameras * sum(_round_up(k, KP_BLOCK) for k in level_ks)
+    return {"describe_bf16_slots":
+            slots if single_pass_taps(slab_dtype) else 0}
 
 
 def fast_score_map(img: jnp.ndarray, threshold: float,

@@ -8,7 +8,9 @@ patch moments and the packed descriptors:
   * the per-image ref oracle (``ref.orient_describe``).
 
 The kernel resolves taps with a selection matmul whose SIGN equals the
-oracle's gather-compare exactly, so equality is exact, not approximate.
+oracle's gather-compare exactly, so equality is exact, not approximate:
+one bf16 MXU pass on integer (uint8) slabs, f32 ``Precision.HIGHEST``
+on float slabs.  Both slab dtypes are held to the oracle.
 Descriptor differences against the pre-refactor EXACT steering
 (``ref.describe_steered``) are bounded by the documented 30-degree
 angle-bin quantization and pinned here.
@@ -19,14 +21,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import analysis
+from repro.analysis import jaxpr_walk
 from repro.core import (ORBConfig, extract_features,
                         extract_features_batched)
 from repro.core import brief, fast
-from repro.kernels import ops, pattern, ref
+from repro.kernels import describe_fused, ops, pattern, ref
+
+SLAB_DTYPES = {"float32": np.float32, "uint8": np.uint8}
 
 
-def _imgs(rng, b, h, w):
-    return jnp.asarray(rng.randint(0, 256, (b, h, w)).astype(np.float32))
+def _imgs(rng, b, h, w, dtype=np.float32):
+    return jnp.asarray(rng.randint(0, 256, (b, h, w)).astype(dtype))
 
 
 def _keypoints(rng, b, k, h, w, border=16):
@@ -56,20 +62,105 @@ def _assert_tri_impl_exact(raw, smoothed, xy):
     return out_pl
 
 
+@pytest.mark.parametrize("slab_dtype", sorted(SLAB_DTYPES))
 @pytest.mark.parametrize("shape,b,k", [
     ((70, 111), 3, 21),      # non-square, K not a KP_BLOCK multiple
     ((96, 128), 4, 8),
     ((37, 53), 2, 5),        # image smaller than one dense tile
 ])
-def test_tri_impl_bitexact(rng, shape, b, k):
+def test_tri_impl_bitexact(rng, shape, b, k, slab_dtype):
     h, w = shape
-    raw = _imgs(rng, b, h, w)
+    raw = _imgs(rng, b, h, w, SLAB_DTYPES[slab_dtype])
     smoothed = ops.fast_blur_nms_batched(raw, 20.0, impl="ref")[0]
+    assert smoothed.dtype == raw.dtype
     xy = _keypoints(rng, b, k, h, w)
     out = _assert_tri_impl_exact(raw, smoothed, xy)
     assert out[0].shape == (b, k)
     assert out[1].shape == (b, k, 2)
     assert out[2].shape == (b, k, 8) and out[2].dtype == jnp.uint32
+
+
+def test_float_slabs_with_fractional_values(rng):
+    """A smoothed float slab off the integers (an unquantized blur),
+    which one bf16 pass would round: its f32 HIGHEST taps keep the
+    descriptors exact."""
+    raw = _imgs(rng, 2, 64, 80)
+    smoothed = ops.fast_blur_nms_batched(raw, 20.0, quantized=False,
+                                         impl="ref")[0]
+    assert not np.array_equal(np.asarray(smoothed),
+                              np.round(np.asarray(smoothed)))
+    _assert_tri_impl_exact(raw, smoothed, _keypoints(rng, 2, 16, 64, 80))
+
+
+def test_uint8_saturated_patches_at_bin_edges(rng):
+    """The single-pass bf16 taps at their extremes: 0/255-only raw and
+    smoothed slabs (the largest pixel, and ties everywhere), and
+    keypoints whose angle lies within BIN_MARGIN of a steering-bin edge,
+    so the kernel steers both candidate bins (b_lo != b_hi) and the
+    caller keeps the oracle's."""
+    b, h, w = 2, 72, 104
+    raw = jnp.asarray(255 * rng.randint(0, 2, (b, h, w)).astype(np.uint8))
+    blocks = rng.randint(0, 2, (b, h // 4 + 1, w // 4 + 1)).astype(np.uint8)
+    smoothed = jnp.asarray(
+        255 * np.repeat(np.repeat(blocks, 4, 1), 4, 2)[:, :h, :w])
+    cand = _keypoints(rng, b, 400, h, w)
+    moments = jax.vmap(lambda im, p: ref.patch_theta(ref.extract_patches(
+        im, p))[1])
+    mom = moments(raw, cand)
+    b_lo, b_hi = describe_fused.candidate_bins(mom[..., 0], mom[..., 1])
+    edge = np.asarray(b_lo != b_hi)
+    # 12 edge keypoints and 12 others a camera, in mixed order
+    picks = [np.concatenate([np.flatnonzero(e)[:12],
+                             np.flatnonzero(~e)[:12]]) for e in edge]
+    assert all(len(p) == 24 for p in picks), edge.sum(axis=1)
+    order = rng.permutation(24)
+    xy = jnp.stack([cand[c, p[order]] for c, p in enumerate(picks)])
+    theta, _, _ = _assert_tri_impl_exact(raw, smoothed, xy)
+    # the oracle's bin falls on each side of the edges
+    mom = moments(raw, xy)
+    _, b_hi = describe_fused.candidate_bins(mom[..., 0], mom[..., 1])
+    on_hi = np.asarray(ref.theta_to_bin(theta) == b_hi)
+    assert 0 < on_hi.sum() < on_hi.size
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` equation of ``jaxpr``, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn)
+        for val in eqn.params.values():
+            for sub in jaxpr_walk._sub_jaxprs(val):
+                out += _dots(sub)
+    return out
+
+
+@pytest.mark.parametrize("entry,taps", [
+    ("frame_u8", (jnp.bfloat16, None)),
+    ("frame_f32", (jnp.float32, jax.lax.Precision.HIGHEST)),
+])
+def test_describe_precision_follows_slab_dtype(entry, taps):
+    """The describe ``pallas_call`` of a session's frame, read from its
+    jaxpr (no chip, no execution): on the uint8 datapath every dot has
+    bf16 operands at default precision; on f32 slabs the tap dots stay
+    f32 HIGHEST and only the bit pack is bf16."""
+    te = analysis.trace_entry(next(s for s in analysis.MATRIX
+                                   if s.name == entry),
+                              height=96, width=128, max_features=64)
+    (site,) = [s for s in te.sites
+               if s.eqn.params.get("name") == "describe_fused_pyramid_pallas"]
+    dots = _dots(site.kernel_jaxpr)
+    packs = [d for d in dots if d.invars[1].aval.shape == (256, 16)]
+    tap_dots = [d for d in dots if d.invars[1].aval.shape == (31, 256)]
+    assert len(packs) == 2 and len(tap_dots) == 4
+    for eqn, (dtype, precision) in (
+            [(d, (jnp.bfloat16, None)) for d in packs]
+            + [(d, taps) for d in tap_dots]):
+        assert [v.aval.dtype for v in eqn.invars] == [dtype, dtype]
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        got = eqn.params["precision"]
+        assert (got if got is None else set(got)) == (
+            None if precision is None else {precision}), got
 
 
 def test_paper_level1_shape(rng):

@@ -18,17 +18,17 @@ H, W = 48, 64
 CHILDREN = ("repro.validate", "repro.frame_call", "repro.localize_call")
 
 
-def _quad(localize=False, **rig_kw):
+def _quad(localize=False, precision="f32", **rig_kw):
     ocfg = ORBConfig(height=H, width=W, max_features=16, n_levels=2,
                      max_disparity=24)
     return VisualSystem(
         RigConfig.quad(CameraIntrinsics(cx=W / 2.0, cy=H / 2.0), **rig_kw),
-        PipelineConfig(orb=ocfg, localize=localize))
+        PipelineConfig(orb=ocfg, localize=localize, precision=precision))
 
 
-def _frame(seed, *lead):
+def _frame(seed, *lead, dtype=np.float32):
     rng = np.random.RandomState(seed)
-    return rng.randint(0, 256, lead + (4, H, W)).astype(np.float32)
+    return rng.randint(0, 256, lead + (4, H, W)).astype(dtype)
 
 
 def _spans(tmp_path):
@@ -132,6 +132,33 @@ def test_frame_calls_carry_their_kernel_grids(tmp_path):
     assert len(calls) == 2
     for s in calls:
         assert {k: int(s[4][k]) for k in want} == want
+
+
+@pytest.mark.parametrize("precision,want", [("uint8", 4032), ("f32", 0)])
+def test_describe_bf16_slots_of_the_quad720_session(precision, want):
+    """The describe's single-pass keypoint slots of a 720p quad session
+    (``ORBConfig()``: 591 + 409 features, 592 + 416 padded to KP_BLOCK,
+    4 cameras), as its frame calls carry them: every slot on the uint8
+    datapath, none on f32 slabs."""
+    vs = VisualSystem(RigConfig.quad(CameraIntrinsics()),
+                      PipelineConfig(precision=precision))
+    assert vs._frame_grids["describe_bf16_slots"] == want
+
+
+@pytest.mark.parametrize("precision,dtype,want", [
+    ("uint8", np.uint8, 4 * (16 + 8)),
+    ("f32", np.float32, 0),
+])
+def test_frame_calls_carry_describe_bf16_slots(tmp_path, precision, dtype,
+                                               want):
+    # 10 + 6 features, padded to 16 + 8 slots, 4 cameras
+    vs = _quad(precision=precision)
+    frame = _frame(5, dtype=dtype)
+    spans = _profiled(tmp_path, vs.process_frame, [frame, frame])
+    calls = [s for s in spans if s[0] == "repro.frame_call"]
+    assert len(calls) == 2
+    for s in calls:
+        assert int(s[4]["describe_bf16_slots"]) == want
 
 
 def test_desync_drop_hands_nothing_over(tmp_path):
